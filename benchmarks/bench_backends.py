@@ -517,6 +517,9 @@ def obs_cell(quick: bool = False) -> int:
 
 
 def main() -> None:
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small graphs, skip the large cell")

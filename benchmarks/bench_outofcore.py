@@ -171,6 +171,9 @@ def smoke(workdir: str) -> None:
 
 
 def main() -> None:
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small graph (CI-friendly); skips the 10M-edge build")

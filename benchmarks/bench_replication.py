@@ -60,6 +60,9 @@ def replica_burst(rep: CoreReplica, rng, num_queries: int) -> list:
 
 
 def main() -> None:
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="1 writer + 2 replicas, bounded-lag assertion (CI)")

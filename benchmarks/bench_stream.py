@@ -225,6 +225,9 @@ def query_burst(svc: CoreService, rng, num_queries: int) -> int:
 
 
 def main() -> None:
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small sizes for CI smoke runs")

@@ -27,8 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "../src"))
 
-from repro.compat.jaxshims import shard_map  # noqa: E402
-from repro.launch.mesh import make_production_mesh, use_mesh  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.dryrun import _metrics, ICI_BW, HBM_BW  # noqa: E402
 
 N = 2_449_029            # ogb_products nodes
@@ -59,7 +58,7 @@ def run():
                       NamedSharding(mesh, P(axes))),
         out_shardings=NamedSharding(mesh, P()),
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         mb = _metrics(fb.lower(h, src, dst).compile())
 
     # ------------- optimized: dst-striped edges -> local partial + all-gather
@@ -70,7 +69,7 @@ def run():
         out = jax.lax.all_gather(local, axes, tiled=True)  # (CHIPS*N_STRIPE, D)
         return out[:N]
 
-    fs = jax.jit(shard_map(
+    fs = jax.jit(jax.shard_map(
         striped, mesh=mesh,
         in_specs=(P(), P(axes), P(axes), P(axes)),
         out_specs=P(), check_vma=False,
@@ -78,7 +77,7 @@ def run():
                      NamedSharding(mesh, P(axes)), NamedSharding(mesh, P(axes))),
        out_shardings=NamedSharding(mesh, P()))
     stripe_lo = sds((CHIPS,), jnp.int32)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         ms = _metrics(fs.lower(h, src, dst, stripe_lo).compile())
 
     rows = {}
@@ -96,4 +95,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     run()

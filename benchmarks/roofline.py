@@ -253,6 +253,9 @@ if __name__ == "__main__":
     import sys
 
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     ap = argparse.ArgumentParser()
     ap.add_argument("mesh", nargs="?", default="single_pod_16x16")
     ap.add_argument("--superstep", action="store_true",

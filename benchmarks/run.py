@@ -26,6 +26,9 @@ def _save(name: str, obj):
 
 
 def main() -> None:
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
     full = os.environ.get("REPRO_BENCH_FULL") == "1"
     from benchmarks import paper_figs
 

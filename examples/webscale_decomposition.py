@@ -13,9 +13,9 @@ consistency).
 
 ``--backend pallas`` demonstrates the paper's block skipping at the kernel
 layer end to end: SemiCore*'s shrinking frontier drives the block-activity
-mask of ``segment_sum_active``, so untouched edge blocks issue no DMA (on
-this CPU container the kernels run in Pallas interpret mode, so the graph is
-scaled down to keep the demo quick; the TPU lowering is the deploy target).
+flags of the fused superstep kernel, so untouched edge blocks issue no DMA.
+The kernel is compiled by Mosaic on a TPU and interpreted on CPU, where the
+graph is scaled down to keep the demo quick.
 
 ``--backend shard`` runs the whole fixpoint on a device mesh (DESIGN.md §13):
 per-device contiguous edge shards, replicated O(n) core, one all_gather of

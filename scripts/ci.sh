@@ -1,17 +1,14 @@
 #!/usr/bin/env bash
-# Tier-1 CI: dev deps (best effort — hermetic images fall back to the
-# repro.compat hypothesis stub), full test suite, streaming bench smoke.
+# Tier-1 CI: full test suite, streaming bench smoke.  The workflow installs
+# the pinned runtime and dev dependencies (.github/workflows/ci.yml).
 #
-# The workflow matrix (.github/workflows/ci.yml) runs this leg at
-# python {3.10, 3.12} x device-count {1, 8}; the 8-device legs export
+# The workflow matrix runs this leg at python 3.12 x device-count {1, 8};
+# the 8-device legs export
 # XLA_FLAGS=--xla_force_host_platform_device_count=8 so the shard backend
 # (engine.ShardedBackend, DESIGN.md §13) exercises a real 8-way mesh on the
 # CPU runner end to end — pytest sweep, backend smoke, and trajectory gate.
 set -euo pipefail
 cd "$(dirname "$0")/.."
-
-python -m pip install -q -r requirements-dev.txt \
-  || echo "WARN: dev-dep install failed; relying on repro.compat fallbacks" >&2
 
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q
 

@@ -553,16 +553,17 @@ class PallasBackend(DeviceBackend):
     once per graph version, not per bind (the old per-``apply_batch``
     O(m) ``np.repeat`` rebuild).
 
-    The hot path fuses the whole superstep into ONE ``pallas_call``
+    The hot path runs the superstep through the fused histogram kernel
     (``kernels.fused_superstep``, DESIGN.md §16): both the device-resident
-    fixpoint and the legacy per-pass methods below dispatch a single fused
-    kernel per pass instead of one ``segment_sum_active`` launch per
-    h-index probe (``REPRO_PALLAS_FUSED=0`` restores the per-probe oracle).
+    fixpoint and the legacy per-pass methods below dispatch one kernel call
+    for the h-index (two per pass for SemiCore+/*) instead of one
+    ``segment_sum_active`` launch per h-index probe
+    (``REPRO_PALLAS_FUSED=0`` restores the per-probe oracle).
 
-    ``interpret=None`` (the default) auto-selects via
-    ``kernels.default_interpret()``: compiled kernels on TPU/GPU hosts, the
-    Pallas interpreter everywhere else (overridable with
-    ``REPRO_PALLAS_INTERPRET``).  Accounting kernel blocks are capped at
+    The platform picks the kernel mode (``kernels.resolve_interpret``):
+    compiled on an accelerator, the Pallas interpreter on CPU; asking for
+    the other mode (``interpret=``, the ``"pallas-interpret"`` name or
+    ``REPRO_PALLAS_INTERPRET``) raises.  Accounting kernel blocks are capped at
     512 edges; the fused kernel's tile size is independently tunable via
     ``REPRO_FUSED_BLOCK_EDGES``.
     """
@@ -661,7 +662,7 @@ class PallasBackend(DeviceBackend):
         }
 
     # -- full-table scans ---------------------------------------------------
-    # Hot path (REPRO_PALLAS_FUSED != 0): ONE pallas_call per superstep —
+    # Hot path (REPRO_PALLAS_FUSED != 0): one fused kernel call per pass —
     # the fused kernel returns (h, cnt_at_h) together, so the SemiCore*
     # pass's compute_cnt(thresholds == h) is served from a per-pass cache
     # with no extra dispatch.  REPRO_PALLAS_FUSED=0 reverts to the PR 3
@@ -678,7 +679,7 @@ class PallasBackend(DeviceBackend):
         from ..kernels import fused_superstep as fsk
 
         if fsk.fused_enabled():
-            ft = self._resident.fused(fsk.fused_block_edges(self.E))
+            ft = self._resident.fused(fsk.fused_block_edges())
             h_j, cnth_j = fsk.fused_hindex(
                 self._core0_j, self._active_j, ft.arrays, dims=ft.dims,
                 num_probes=num_probes, interpret=self._interpret)
@@ -708,13 +709,10 @@ class PallasBackend(DeviceBackend):
             if cache is not None and np.array_equal(
                     cache[0], np.asarray(thresholds, dtype=np.int64)):
                 return cache[1]
-            tmax = int(np.max(thresholds)) if F else 0
-            num_probes = max(1, int(np.ceil(np.log2(tmax + 2))))
-            ft = self._resident.fused(fsk.fused_block_edges(self.E))
+            ft = self._resident.fused(fsk.fused_block_edges())
             cnt = fsk.fused_counts(
                 self._core0_j, jnp.asarray(thr), self._active_j, ft.arrays,
-                dims=ft.dims, num_probes=num_probes,
-                interpret=self._interpret)
+                dims=ft.dims, interpret=self._interpret)
             return np.asarray(cnt).astype(np.int64)[self._frontier]
         _, counts = _pallas_full_ops(self.be, self._interpret)
         cnt = counts(self._core0_j, self._nbr_j, self._rows_j, self._active_j,
@@ -809,7 +807,10 @@ def resolve_backend(backend) -> ComputeBackend:
     if name == "pallas":
         return PallasBackend()
     if name == "pallas-interpret":
-        return PallasBackend(interpret=True)
+        # the CPU spelling of "pallas": refused on an accelerator
+        backend = PallasBackend(interpret=True)
+        backend._resolve_interpret()
+        return backend
     if name == "shard":
         ns = os.environ.get("REPRO_NUM_SHARDS")
         return ShardedBackend(num_shards=int(ns) if ns else None)

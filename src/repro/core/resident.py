@@ -312,8 +312,8 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
     gathers; XLA CPU scatters would serialize it).
 
     With ``fused`` (the pallas hot path, DESIGN.md §16) each superstep is
-    ONE ``pallas_call`` — ``kernels.fused_superstep.fused_pass`` replaces
-    the whole per-probe body; the scan/cond convergence scaffolding and
+    one ``kernels.fused_superstep.fused_pass`` (one histogram kernel call,
+    two for semicore+/*) in place of the whole per-probe body; the scan/cond convergence scaffolding and
     every returned summary are identical, so the host replay is untouched.
     The static ``dims`` tuple rides the kernel table (same trace-count
     contract: only shapes and the probe count retrace).
@@ -586,15 +586,13 @@ def _counts_all_fn(kind: str, block_edges: int, interpret: bool,
     if fused:
         from ..kernels import fused_superstep as fsk
 
-        def counts_all(core, arrs, *, num_segments, num_probes, dims):
+        def counts_all(core, arrs, *, num_segments, dims):
             _TRACE_COUNT[0] += 1
             all_active = jnp.ones((num_segments,), jnp.bool_)
             return fsk.fused_counts(core, core, all_active, arrs, dims=dims,
-                                    num_probes=num_probes,
                                     interpret=interpret)
 
-        return jax.jit(counts_all, static_argnames=("num_segments",
-                                                    "num_probes", "dims"))
+        return jax.jit(counts_all, static_argnames=("num_segments", "dims"))
 
     for_pass = _substrate(kind, block_edges, interpret)
 
@@ -720,7 +718,7 @@ def run_resident(engine, algorithm: str, backend, *,
         the fused path ships the compact-rank kernel table, the per-probe
         paths the flat edge table (bucket-padded for xla, exact for pallas)."""
         if fused:
-            ft = rs.fused(fsk.fused_block_edges(rs.E))
+            ft = rs.fused(fsk.fused_block_edges())
             return (ft.arrays,), {"dims": ft.dims}
         return (nbr_j, rows_j, rs.segptr_j), {}
 
@@ -772,8 +770,7 @@ def run_resident(engine, algorithm: str, backend, *,
                 if rs.E and fused:
                     counts_all = _counts_all_fn(kind, be, interpret, True)
                     sargs, skw = substrate_args()
-                    cnt_j = counts_all(core_j, *sargs, num_segments=n,
-                                       num_probes=num_probes, **skw)
+                    cnt_j = counts_all(core_j, *sargs, num_segments=n, **skw)
                 elif rs.E:
                     counts_all = _counts_all_fn(kind, be, interpret)
                     cnt_j = counts_all(core_j, nbr_j, rows_j,
@@ -1062,7 +1059,6 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..compat.jaxshims import shard_map
     from .engine import edge_ge_counts, hindex_bsearch
 
     axes = tuple(mesh.axis_names)
@@ -1239,8 +1235,8 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
     else:
         raise ValueError(f"unknown algorithm {algorithm!r}")
 
-    sharded = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_vma=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return jax.jit(
         sharded,
         in_shardings=tuple(NamedSharding(mesh, s) for s in in_specs),
@@ -1264,7 +1260,6 @@ def _shard_counts_fn(mesh, n: int):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..compat.jaxshims import shard_map
     from .engine import edge_ge_counts
 
     axes = tuple(mesh.axis_names)
@@ -1284,8 +1279,8 @@ def _shard_counts_fn(mesh, n: int):
         return cnt[None]
 
     in_specs = (repl, shard, shard, shard, shard, shard, shard)
-    sharded = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=shard, check_vma=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=shard, check_vma=False)
     return jax.jit(
         sharded,
         in_shardings=tuple(NamedSharding(mesh, s) for s in in_specs),

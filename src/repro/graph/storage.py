@@ -126,17 +126,16 @@ class CSRGraph:
             key = lo * np.int64(n) + hi
             _, idx = np.unique(key, return_index=True)
             edges = np.stack([lo[idx], hi[idx]], axis=1)
-        # symmetrize
-        src = np.concatenate([edges[:, 0], edges[:, 1]])
-        dst = np.concatenate([edges[:, 1], edges[:, 0]])
-        order = np.argsort(src, kind="stable")
-        src, dst = src[order], dst[order]
+        # symmetrize, then one sort by (src, dst): rows in order, neighbor
+        # lists sorted, for deterministic layouts
+        key = np.concatenate([edges[:, 0] * np.int64(n) + edges[:, 1],
+                              edges[:, 1] * np.int64(n) + edges[:, 0]])
+        key.sort()
+        src = key // max(n, 1)
         counts = np.bincount(src, minlength=n).astype(np.int64)
         indptr = np.zeros(n + 1, dtype=np.int64)
         np.cumsum(counts, out=indptr[1:])
-        # sort neighbors within each list for deterministic layouts
-        order2 = np.lexsort((dst, src))
-        out = dst[order2].astype(np.int32)
+        out = (key - src * np.int64(n)).astype(np.int32)
         return cls(indptr=indptr, adj=out)
 
     def edge_list(self) -> np.ndarray:

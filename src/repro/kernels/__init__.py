@@ -1,51 +1,41 @@
-"""Pallas TPU kernels (interpret-validated on CPU; TPU is the target).
+"""Pallas TPU kernels (compiled by Mosaic on TPU, interpreted on CPU).
 
 segsum         -- blocked one-hot-matmul segment sum (edge scans: LocalCore
                   counts, GNN aggregation, bag pooling)
 embedding_bag  -- scalar-prefetch gather-pool (recsys tables)
 flash_decode   -- blocked long-KV decode attention (long_500k cells)
-fused_superstep-- the whole decomposition superstep as ONE pallas_call
-                  (h-index histogram, cnt refresh, push rule, convergence
-                  flag) with activity-masked block DMA (DESIGN.md §16)
+fused_superstep-- the decomposition superstep's histogram kernel (h-index,
+                  cnt refresh, neighbour counts) with activity-masked block
+                  DMA (DESIGN.md §16)
 
-``default_interpret`` is the single policy for the historical scattered
-``interpret: bool = True`` kernel defaults: compiled lowering on real
-accelerators, the Pallas interpreter elsewhere, ``REPRO_PALLAS_INTERPRET``
-forcing either way.
+``resolve_interpret`` is the single interpret-mode policy: the platform
+decides.  Kernels are interpreted on CPU, where nothing else can run them,
+and compiled everywhere else.  A request for the other mode — an explicit
+``interpret=`` or ``REPRO_PALLAS_INTERPRET`` — raises instead of quietly
+emulating on an accelerator.
 """
 from __future__ import annotations
 
-import os
-
 __all__ = ["segment_sum", "segment_sum_active", "embedding_bag",
-           "flash_decode", "default_interpret", "resolve_interpret",
-           "INTERPRET_ENV_VAR"]
-
-INTERPRET_ENV_VAR = "REPRO_PALLAS_INTERPRET"
-
-
-def default_interpret() -> bool:
-    """Interpret-mode default for every kernel in this package.
-
-    ``REPRO_PALLAS_INTERPRET`` (0/false vs anything else) wins when set;
-    otherwise kernels lower for real on TPU/GPU hosts and fall back to the
-    Pallas interpreter on CPU containers (the only option there).  This
-    replaces the old per-signature ``interpret: bool = True`` defaults that
-    silently emulated on real hardware.
-    """
-    from repro import runtime as _runtime
-
-    resolved = _runtime.setting("pallas_interpret")
-    if resolved is not None:
-        return resolved
-    import jax
-
-    return jax.default_backend() not in ("tpu", "gpu")
+           "flash_decode", "resolve_interpret"]
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    """``None`` -> :func:`default_interpret`; explicit values pass through."""
-    return default_interpret() if interpret is None else bool(interpret)
+    """Interpret mode exactly when JAX's default platform is the CPU.
+    ``interpret`` or ``REPRO_PALLAS_INTERPRET``, where given, must agree."""
+    import jax
+
+    from repro import runtime as _runtime
+
+    mode = jax.default_backend() == "cpu"
+    want = interpret if interpret is not None else \
+        _runtime.setting("pallas_interpret")
+    if want is not None and bool(want) != mode:
+        raise ValueError(
+            f"Pallas interpret={bool(want)} requested on platform "
+            f"{jax.default_backend()!r}, which runs kernels "
+            f"{'interpreted' if mode else 'compiled'} only")
+    return mode
 
 
 # Bound eagerly (as the functions, not the same-named submodules — the

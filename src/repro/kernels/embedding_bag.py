@@ -40,7 +40,7 @@ def embedding_bag_pallas(
 ) -> jax.Array:
     """Sum-pooled bags: out[b] = sum_l weights[b,l] * table[indices[b,l]].
 
-    ``interpret`` -- None defers to ``kernels.default_interpret()``.
+    ``interpret`` -- None defers to ``kernels.resolve_interpret``.
     """
     from . import resolve_interpret
     interpret = resolve_interpret(interpret)

@@ -1,52 +1,50 @@
-"""Fused single-kernel Pallas superstep (DESIGN.md §16).
+"""Fused Pallas superstep (DESIGN.md §16).
 
-One ``pallas_call`` per decomposition pass, replacing the per-probe
-``segment_sum_active`` dispatch (~``log2(kmax)`` kernel launches per pass,
-each paying per-grid-step interpreter overhead).  The whole superstep —
-h-index, cnt refresh, push-decrement rule, convergence counter — runs in a
-single grid over edge blocks:
+One decomposition pass is one histogram ``pallas_call`` for the h-index and
+the refreshed cnt, plus (semicore+ / semicore*) one counting call for the
+neighbour rule.  The same kernel serves both, and it has a single lowering:
+the Pallas interpreter on CPU runs exactly the code that Mosaic compiles for
+the TPU.
 
-  grid = (P, nbk)   P = 1 (semicore / hindex / counts) or 2 (semicore+/*)
-                    nbk = ceil(E / block_edges); b iterates fastest, so all
-                    phase-0 steps complete before any phase-1 step runs.
+  grid = (nbk,)   nbk = ceil(E / block_edges) edge blocks, in order.
 
-  phase 0  streams edge blocks and accumulates a per-row histogram of
-           *capped* neighbor values (``min(nbr_core, cap_row)``) into a VMEM
-           scratch; the last block finalizes h (monotone-predicate count over
-           the suffix histogram), the refreshed cnt (suffix at h), the
-           convergence counter, and parks core2 (or changed flags) in scratch
-           for phase 1.
-  phase 1  streams the same blocks and window-sums the push-decrement
-           predicate (semicore*) or changed-neighbor indicator (semicore+)
-           per row.
+  accumulate  every active block adds its edges to a per-row histogram of
+              *capped* neighbour values (``min(nbr_core, core_row)``) kept in
+              VMEM scratch.  The scatter is two one-hot matrices multiplied
+              on the MXU: values x edges (bins) against rows x edges (the
+              block's row window), bf16 one-hots with f32 accumulation, so
+              every count is an exact integer.
+  finalize    the last block turns each row's histogram into h (binary
+              search over the monotone predicate #(vals >= k) >= k, each
+              probe a masked sublane sum) and into #(vals >= h).
 
-Activity masking happens *inside* the kernel's index maps: the scalar-
-prefetched ``(3, nbk)`` table carries [row-activity, nbr-activity, firsts]
-per block, and inactive blocks keep their index map pinned to block 0 so the
-pipeline never issues their HBM->VMEM DMA (same trick as
-``segsum_active.py``); compute for those steps is skipped with ``pl.when``.
-Double buffering comes from the Pallas grid pipeline itself: streamed
-BlockSpec fetches for step b+1 overlap step b's compute exactly as
-``flash_decode.py`` overlaps KV-block DMA.
+Everything that gathers across the whole node table (neighbour cores, the
+per-edge caps, the semicore* push predicate, the semicore+ changed-neighbour
+flag) runs in XLA between the calls; the kernel only streams per-edge values
+and row offsets.  A count is the same kernel on values in {0, -1}: h is 0 and
+the second output is the number of edges whose value is 0.
 
-Compact rank space
-------------------
-Rows are addressed by the dense rank of their sorted position among rows
-with >= 1 edge.  Consecutive edges' ranks differ by <= 1, so every block's
-row span fits in ``block_edges`` rows — which makes the windowed
-scratch read-modify-write (``hist[first:first+cbe] += counts``) well defined
-even for graphs with many isolated nodes (in global row space, empty rows
-could stretch a block's span arbitrarily).  Zero-degree rows can never be
-active (core = deg = 0), so globalizing through ``rank``/``present`` is
-exact.
+Activity masking happens inside the index maps: a scalar-prefetched
+per-block flag keeps an inactive block's index map pinned to block 0, so the
+pipeline never issues its HBM->VMEM DMA (same trick as ``segsum_active.py``),
+and ``pl.when`` skips its compute.  Double buffering comes from the Pallas
+grid pipeline itself.
 
-Histograms have two lowerings picked by the ``interpret`` flag: a per-edge
-scatter-add (O(cbe) per step — fastest on CPU/interpret, where XLA scatters
-are cheap but scans are not) and a one-hot cumulative-sum + window-boundary
-gather (O(cbe*K) per step — scatters don't lower in Mosaic, the cumsum form
-vectorizes on the VPU).  Both produce bit-identical f32 counts: every
-addend is 1.0 and ``dmax < 2**24`` is validated at structure-build time
-(engine.py).
+Layout
+------
+Rows are addressed by their compact rank among rows with >= 1 edge, so
+consecutive edges' ranks differ by <= 1 and a block of ``cbe`` edges spans at
+most ``cbe`` ranks.  Per-row state is lane-dense: rank r lives at
+``[r // 128, :, r % 128]``, the histogram scratch is ``(Rb, K, 128)`` (bins
+on sublanes) and each per-row output ``(Rb, 1, 128)``.  A block's window
+starts at the 128-aligned group holding its first rank and covers ``nwin``
+groups; every dynamic index is on the untiled leading axis.
+
+Counts are f32 (exact below 2**24; ``dmax < 2**24`` is validated at
+structure-build time, engine.py).  The VMEM footprint and the SMEM of the
+per-block tables are computed from the dims and checked against the limits
+the kernel requests before anything is traced, so a graph that does not fit
+raises ``ValueError`` instead of a compiler error.
 
 Everything the engine observes — core/cnt/iters traces, planner I/O
 accounting, kernel_blocks_active/skipped — is bit-identical to the per-probe
@@ -58,7 +56,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import NamedTuple
 
 import numpy as np
@@ -71,9 +68,16 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = [
     "FusedArrays", "FusedTable", "build_fused_table", "fused_pass",
     "fused_hindex", "fused_counts", "fused_enabled", "fused_block_edges",
+    "vmem_bytes", "check_memory", "VMEM_LIMIT_BYTES", "SMEM_LIMIT_BYTES",
 ]
 
-_FALSY = ("0", "false", "no", "off")
+LANES = 128
+#: scoped VMEM the kernel requests (a v5e TensorCore has 128 MiB)
+VMEM_LIMIT_BYTES = 100 * 2 ** 20
+#: SMEM budget of the two scalar-prefetched per-block tables (1 MiB on v5e)
+SMEM_LIMIT_BYTES = 512 * 2 ** 10
+_BIN_CHUNK = 256  # value bins per one-hot matmul
+_DEFAULT_TILE = 512
 
 
 def fused_enabled() -> bool:
@@ -86,14 +90,13 @@ def fused_enabled() -> bool:
     return _runtime.setting("pallas_fused")
 
 
-def fused_block_edges(num_edges: int | None = None) -> int:
+def fused_block_edges() -> int:
     """Kernel tile size in edges (``REPRO_FUSED_BLOCK_EDGES``); independent
     of the planner's accounting block size.
 
-    Without an env override the tile adapts to the graph: ~24 grid steps
-    per phase (next pow2 of ``num_edges / 24``, clamped to [512, 8192]).
-    Per-step interpreter overhead dominates small tiles on big graphs, while
-    oversized tiles waste the tail block on small ones.
+    Without an env override the tile is 512 edges: the row window is a
+    one-hot matrix, so a block's MXU work and its VMEM temporaries grow
+    with the square of the tile.
     """
     from repro import runtime as _runtime
 
@@ -103,38 +106,27 @@ def fused_block_edges(num_edges: int | None = None) -> int:
             raise ValueError(
                 f"REPRO_FUSED_BLOCK_EDGES must be >= 8, got {v}")
         return v
-    if not num_edges:
-        return 512
-    v = 512
-    while v < min(num_edges // 24, 8192):
-        v <<= 1
-    return v
+    return _DEFAULT_TILE
 
 
 class FusedArrays(NamedTuple):
     """Device-resident kernel operands (a jit-friendly pytree of arrays).
 
-    Shapes below use Ep = nbk * cbe (padded edges), R = max(U, 1) + cbe
-    (padded compact rank space, sized so every windowed scratch access of
-    length cbe starting at a valid rank stays in bounds).
+    Ep = nbk * cbe padded edges; pad edges have ``ev`` False.
     """
-    nbr: jnp.ndarray      # (Ep,)  i32 neighbor node id per edge (pad: 0)
-    ev: jnp.ndarray       # (Ep,)  bool edge-validity (False on pads)
-    compact: jnp.ndarray  # (Ep,1) i32 compact rank of each edge's row
-    nbrc: jnp.ndarray     # (Ep,1) i32 compact rank of each edge's neighbor
-    cptr: jnp.ndarray     # (R+1,1) i32 compact CSR ptr, padded with E
-    seg_of: jnp.ndarray   # (R,)   i32 node id of each rank (pad: 0)
-    validc: jnp.ndarray   # (R,)   bool rank < U
-    rank: jnp.ndarray     # (n,)   i32 rank of each node (0 if absent)
-    present: jnp.ndarray  # (n,)   bool node has >= 1 edge
-    firsts: jnp.ndarray   # (nbk,) i32 rank of first edge in block b
-    lasts: jnp.ndarray    # (nbk,) i32 rank of last edge in block b
+    nbr: jnp.ndarray      # (Ep,)         i32 neighbour node id (pad: 0)
+    row: jnp.ndarray      # (Ep,)         i32 source node id (pad: 0)
+    ev: jnp.ndarray       # (Ep,)         bool edge validity
+    local: jnp.ndarray    # (nbk, 1, cbe) i32 row rank - 128 * base[b]
+    base: jnp.ndarray     # (nbk,)        i32 first lane group of block b
+    rank: jnp.ndarray     # (n,)          i32 compact rank (0 if absent)
+    present: jnp.ndarray  # (n,)          bool node has >= 1 edge
 
 
 @dataclasses.dataclass(frozen=True)
 class FusedTable:
     """Static dims + device arrays for one (structure, tile-size) pair."""
-    dims: tuple  # (cbe, Ep, nbk, U, R, n, E) — all python ints, hashable
+    dims: tuple  # (cbe, nbk, nwin, Rb, n, E) — all python ints, hashable
     arrays: FusedArrays
 
 
@@ -153,301 +145,241 @@ def build_fused_table(seg_ptr, nbr, n: int, block_edges: int) -> FusedTable:
     U = int(pres_idx.shape[0])
     nbk = max(1, -(-E // cbe))
     Ep = nbk * cbe
-    R = max(U, 1) + cbe
+    # a block spans <= cbe ranks from an arbitrary offset within its first
+    # lane group, so its window covers this many 128-row groups
+    nwin = (cbe + LANES - 2) // LANES + 1
+    Rb = -(-max(U, 1) // LANES) + nwin
 
     rank = np.zeros(n, dtype=np.int32)
     rank[pres_idx] = np.arange(U, dtype=np.int32)
-    if E:
-        rows = np.repeat(np.arange(n, dtype=np.int32), lens)
-        compact = rank[rows]
-        pad_rank = int(compact[-1])
-    else:
-        compact = np.zeros(0, dtype=np.int32)
-        pad_rank = 0
-    compact_p = np.full(Ep, pad_rank, dtype=np.int32)
-    compact_p[:E] = compact
+    rows = np.repeat(np.arange(n, dtype=np.int32), lens)
+    compact = np.zeros(Ep, dtype=np.int32)
+    compact[:E] = rank[rows]
+    compact[E:] = compact[E - 1] if E else 0
+    base = compact[0::cbe] // LANES
+    local = compact.reshape(nbk, cbe) - (base * LANES)[:, None]
+    row_p = np.zeros(Ep, dtype=np.int32)
+    row_p[:E] = rows
     nbr_p = np.zeros(Ep, dtype=np.int32)
     nbr_p[:E] = nbr_h
-    nbrc_p = rank[nbr_p]  # neighbors have deg >= 1, so always present
-
-    cptr = np.full(R + 1, E, dtype=np.int64)
-    cptr[:U] = seg_ptr[pres_idx]
-    seg_of = np.zeros(R, dtype=np.int32)
-    seg_of[:U] = pres_idx
-    validc = np.arange(R) < U
-    ev = np.arange(Ep) < E
-    firsts = compact_p[0::cbe].copy()
-    lasts = compact_p[cbe - 1::cbe].copy()
 
     arrays = FusedArrays(
         nbr=jnp.asarray(nbr_p),
-        ev=jnp.asarray(ev),
-        compact=jnp.asarray(compact_p)[:, None],
-        nbrc=jnp.asarray(nbrc_p)[:, None],
-        cptr=jnp.asarray(cptr.astype(np.int32))[:, None],
-        seg_of=jnp.asarray(seg_of),
-        validc=jnp.asarray(validc),
+        row=jnp.asarray(row_p),
+        ev=jnp.asarray(np.arange(Ep) < E),
+        local=jnp.asarray(local.astype(np.int32)[:, None, :]),
+        base=jnp.asarray(base.astype(np.int32)),
         rank=jnp.asarray(rank),
         present=jnp.asarray(present),
-        firsts=jnp.asarray(firsts),
-        lasts=jnp.asarray(lasts),
     )
-    return FusedTable(dims=(cbe, Ep, nbk, U, R, int(n), E), arrays=arrays)
+    return FusedTable(dims=(cbe, nbk, nwin, Rb, int(n), E), arrays=arrays)
 
 
-_N_OUT = {"semicore": 2, "semicore+": 3, "semicore*": 4,
-          "hindex": 3, "counts": 1}
+def _bins(num_probes: int) -> int:
+    return max(8, 1 << int(num_probes))
 
 
-def _superstep_kernel(scal_ref, core0_ref, active_ref, cptr_ref,
-                      compact_ref, nbrc_ref, nval_ref, *refs,
-                      cbe: int, K: int, nbk: int, R: int, E: int,
-                      mode: str, scatter: bool):
-    n_out = _N_OUT[mode]
-    outs = refs[:n_out]
-    hist_ref, acc_ref, core2s_ref = refs[n_out:]
-    p = pl.program_id(0)
-    b = pl.program_id(1)
+def vmem_bytes(dims, num_probes: int) -> int:
+    """VMEM the histogram kernel needs at these dims: the scratch, the
+    double-buffered blocks and the largest temporaries of each phase."""
+    cbe, nbk, nwin, Rb, n, E = dims
+    K = _bins(num_probes)
+    kc = min(K, _BIN_CHUNK)
+    lanes = -(-cbe // LANES) * LANES
+    hist = Rb * K * LANES * 4
+    outs = 2 * 2 * Rb * 8 * LANES * 4        # (1, 128) rows pad to 8 sublanes
+    blocks = 2 * 2 * 8 * lanes * 4           # vals + local, double-buffered
+    onehots = (nwin * LANES * lanes * 2      # row one-hots, bf16
+               + kc * lanes * (4 + 2)        # value one-hot + its compare
+               + LANES * lanes * 4)          # row compare being converted
+    partial = 2 * kc * LANES * 4             # matmul result + window slab
+    finalize = 4 * K * LANES * 4             # histogram slab + masked copies
+    return hist + outs + blocks + onehots + partial + finalize
 
-    @pl.when((p == 0) & (b == 0))
+
+def check_memory(dims, num_probes: int) -> None:
+    """Raise ``ValueError`` where the kernel at these dims would not fit the
+    VMEM it requests or the SMEM of its per-block tables."""
+    cbe, nbk, nwin, Rb, n, E = dims
+    need = vmem_bytes(dims, num_probes)
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"fused superstep needs {need} B of VMEM for {Rb * LANES} rows x "
+            f"{_bins(num_probes)} bins, over the {VMEM_LIMIT_BYTES} B it may "
+            "request; this graph's size and max core want the xla backend")
+    if 2 * 4 * nbk > SMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"fused superstep: {nbk} edge blocks overflow the "
+            f"{SMEM_LIMIT_BYTES} B of SMEM for its per-block tables; use a "
+            "larger REPRO_FUSED_BLOCK_EDGES or the xla backend")
+
+
+def _hist_kernel(on_ref, base_ref, vals_ref, local_ref, h_ref, cnt_ref,
+                 hist_ref, *, K: int, kc: int, nwin: int, Rb: int, nbk: int):
+    b = pl.program_id(0)
+
+    @pl.when(b == 0)
     def _init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        # phase 0 reads this as the per-row probe cap (pass-start core for
-        # active rows, 0 — i.e. "frozen" — otherwise); the phase-0 finalize
-        # overwrites it with core2 / changed flags for phase 1.
-        core2s_ref[...] = jnp.where(active_ref[...] > 0, core0_ref[...], 0)
 
-    first = scal_ref[2, b]
-    bstart = b * cbe
+    @pl.when(on_ref[b] > 0)
+    def _accumulate():
+        vals = vals_ref[...]     # (1, cbe) capped value per edge, -1: none
+        local = local_ref[...]   # (1, cbe) row rank relative to the window
+        cbe = vals.shape[1]
+        base = base_ref[b]
+        rows_oh = [
+            (jax.lax.broadcasted_iota(jnp.int32, (LANES, cbe), 0)
+             + w * LANES == local).astype(jnp.bfloat16)
+            for w in range(nwin)]
 
-    def windows():
-        # per-row edge windows: cptr bounds are <= E, so pad edges in the
-        # tail block fall outside every [lo, hi) and contribute nothing
-        cw = cptr_ref[pl.ds(first, cbe + 1), :][:, 0]
-        lo = jnp.clip(cw[:-1] - bstart, 0, cbe)
-        hi = jnp.clip(cw[1:] - bstart, 0, cbe)
-        return lo, hi
+        def bin_chunk(c, carry):
+            k0 = pl.multiple_of(c * kc, kc)
+            vals_oh = (jax.lax.broadcasted_iota(jnp.int32, (kc, cbe), 0)
+                       + k0 == vals).astype(jnp.bfloat16)
+            for w in range(nwin):
+                part = jax.lax.dot_general(
+                    vals_oh, rows_oh[w], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # (kc, LANES)
+                hist_ref[base + w, pl.ds(k0, kc), :] += part
+            return carry
 
-    @pl.when((p == 0) & (scal_ref[0, b] > 0))
-    def _histogram():
-        local = jnp.clip(compact_ref[...][:, 0] - first, 0, cbe - 1)
-        cap = jnp.take(core2s_ref[pl.ds(first, cbe), :][:, 0], local)
-        vals = jnp.minimum(nval_ref[...][:, 0], cap)
-        if scatter:
-            # interpret / CPU path: one scatter-add per edge, O(cbe) work.
-            # Pad edges in the tail block get weight 0 (caps are < K, so
-            # every valid capped value lands in-range without clipping).
-            valid = (bstart + jax.lax.iota(jnp.int32, cbe)) < E
-            idx = local * K + jnp.clip(vals, 0, K - 1)
-            win = hist_ref[pl.ds(first, cbe), :].reshape(-1)
-            win = win.at[idx].add(valid.astype(jnp.float32))
-            hist_ref[pl.ds(first, cbe), :] = win.reshape(cbe, K)
-        else:
-            # compiled TPU path: scatters don't lower in Mosaic, so build
-            # the same counts from a one-hot cumsum + boundary gathers
-            # (O(cbe*K), vectorizes on the VPU)
-            lo, hi = windows()
-            onehot = (vals[:, None] ==
-                      jax.lax.broadcasted_iota(jnp.int32, (cbe, K), 1))
-            pc = jnp.concatenate(
-                [jnp.zeros((1, K), jnp.float32),
-                 jnp.cumsum(onehot.astype(jnp.float32), axis=0)], axis=0)
-            counts = jnp.take(pc, hi, axis=0) - jnp.take(pc, lo, axis=0)
-            hist_ref[pl.ds(first, cbe), :] += counts
+        jax.lax.fori_loop(0, K // kc, bin_chunk, 0)
 
-    @pl.when((p == 0) & (b == nbk - 1))
-    def _finalize_h():
-        histU = hist_ref[...]                       # (R, K)
-        incl = jnp.cumsum(histU, axis=1)
-        total = incl[:, K - 1:K]
-        suffix = total - incl + histU               # suffix[:, k] = #vals>=k
-        ks = jax.lax.broadcasted_iota(jnp.float32, (R, K), 1)
-        act = active_ref[...] > 0
-        core0 = core0_ref[...]
-        if mode == "counts":
-            # cnt at an arbitrary threshold: #(min(v, thr) >= thr)
-            # == #(v >= thr); core2s still holds the caps here.
-            capf = core2s_ref[...].astype(jnp.float32)
-            cnt = jnp.sum(histU * (ks >= capf), axis=1, keepdims=True)
-            outs[0][...] = jnp.rint(cnt).astype(jnp.int32)
-            return
-        # h = max feasible k: the predicate suffix[k] >= k is monotone in k,
-        # so the count of feasible k in [1, K) *is* the max.  Caps make this
-        # min(h_true, cap) — exactly hindex_bsearch's bounded answer.
-        feas = (suffix >= ks) & (ks >= 1.0)
-        h = jnp.sum(feas.astype(jnp.float32), axis=1, keepdims=True)
-        h32 = jnp.rint(h).astype(jnp.int32)
-        outs[0][...] = h32
-        if mode in ("hindex", "semicore*"):
-            # refreshed cnt: #(v >= h) == suffix at h (h <= cap)
-            refr = jnp.sum(histU * (ks >= h), axis=1, keepdims=True)
-            outs[1][...] = jnp.rint(refr).astype(jnp.int32)
-        outs[-1][0, 0] = jnp.sum((act & (h32 != core0)).astype(jnp.int32))
-        if mode == "semicore*":
-            core2s_ref[...] = jnp.where(act, h32, core0)
-        elif mode == "semicore+":
-            core2s_ref[...] = (act & (h32 != core0)).astype(jnp.int32)
+    @pl.when(b == nbk - 1)
+    def _finalize():
+        kio = jax.lax.broadcasted_iota(jnp.int32, (K, LANES), 0)
 
-    if mode in ("semicore+", "semicore*"):
-        @pl.when((p == 1) & (scal_ref[1, b] > 0))
-        def _accum_phase1():
-            nbrc = nbrc_ref[...][:, 0]
-            c2n = jnp.take(core2s_ref[...][:, 0], nbrc)
-            if mode == "semicore*":
-                local = jnp.clip(compact_ref[...][:, 0] - first, 0, cbe - 1)
-                c2r = jnp.take(core2s_ref[pl.ds(first, cbe), :][:, 0], local)
-                nv = nval_ref[...][:, 0]
-                # == act_nbr & (core2_row > h_nbr) & (core2_row <= c_old_nbr):
-                # an inactive neighbor has c2n == nv, an empty interval.
-                contrib = ((c2r > c2n) & (c2r <= nv)).astype(jnp.float32)
-            else:
-                contrib = c2n.astype(jnp.float32)   # changed-neighbor flag
-            lo, hi = windows()
-            pc = jnp.concatenate(
-                [jnp.zeros((1,), jnp.float32), jnp.cumsum(contrib)])
-            acc_ref[pl.ds(first, cbe), :] += (
-                jnp.take(pc, hi) - jnp.take(pc, lo))[:, None]
+        def row_group(r, carry):
+            hist = hist_ref[r]   # (K, LANES)
 
-        @pl.when((p == 1) & (b == nbk - 1))
-        def _finalize_phase1():
-            out = jnp.rint(acc_ref[...]).astype(jnp.int32)
-            if mode == "semicore*":
-                outs[2][...] = out                  # push decrements
-            else:
-                outs[1][...] = out                  # touched counts
+            def count_ge(t):
+                return jnp.sum(jnp.where(kio >= t, hist, 0.0), axis=0,
+                               keepdims=True)
 
+            # h = max k with #(vals >= k) >= k; the predicate is monotone in
+            # k and vals are capped by the row's core, so this is
+            # min(h_true, cap) — hindex_bsearch's bounded answer
+            h = jnp.zeros((1, LANES), jnp.int32)
+            step = K // 2
+            while step:
+                cand = h + step
+                h = jnp.where(count_ge(cand) >= cand.astype(jnp.float32),
+                              cand, h)
+                step //= 2
+            h_ref[r] = h
+            cnt_ref[r] = count_ge(h).astype(jnp.int32)
+            return carry
 
-def _check_vmem(R: int, K: int, limit: int = 1 << 26):
-    if R * K > limit:
-        raise ValueError(
-            f"fused superstep histogram scratch {R}x{K} exceeds the VMEM "
-            f"budget ({R * K} > {limit} f32 elems); this graph's kmax/size "
-            "wants the xla backend (or a smaller REPRO_FUSED_BLOCK_EDGES)")
+        jax.lax.fori_loop(0, Rb, row_group, 0)
 
 
 @functools.lru_cache(maxsize=None)
-def _fused_call(dims, num_probes: int, mode: str, interpret: bool):
-    cbe, Ep, nbk, U, R, n, E = dims
-    K = max(8, 1 << int(num_probes))
-    _check_vmem(R, K)
-    kernel = functools.partial(_superstep_kernel, cbe=cbe, K=K, nbk=nbk,
-                               R=R, E=E, mode=mode, scatter=interpret)
+def _hist_call(dims, num_probes: int, interpret: bool):
+    cbe, nbk, nwin, Rb, n, E = dims
+    check_memory(dims, num_probes)
+    K = _bins(num_probes)
+    kernel = functools.partial(_hist_kernel, K=K, kc=min(K, _BIN_CHUNK),
+                               nwin=nwin, Rb=Rb, nbk=nbk)
 
-    def const(p, b, scal):
-        return (0, 0)
+    def stream(b, on, base):
+        # activity-masked DMA: an inactive block re-points its fetch at
+        # block 0, so the pipeline never pulls its bytes from HBM
+        return (jnp.where(on[b] > 0, b, 0), 0, 0)
 
-    def stream(p, b, scal):
-        # activity-masked DMA: an inactive (p, b) step re-points its block
-        # fetch at block 0, so the pipeline never pulls its bytes from HBM
-        return (jnp.where(scal[p, b] > 0, b, 0), 0)
+    def whole(b, on, base):
+        return (0, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((R, 1), const),            # core0 (or thresholds)
-        pl.BlockSpec((R, 1), const),            # active mask
-        pl.BlockSpec((R + 1, 1), const),        # compact csr ptr
-        pl.BlockSpec((cbe, 1), stream),         # compact row ranks
-        pl.BlockSpec((cbe, 1), stream),         # neighbor ranks
-        pl.BlockSpec((cbe, 1), stream),         # neighbor core values
-    ]
-    out_defs = {
-        "semicore": [(R, 1), (1, 1)],
-        "semicore+": [(R, 1), (R, 1), (1, 1)],
-        "semicore*": [(R, 1), (R, 1), (R, 1), (1, 1)],
-        "hindex": [(R, 1), (R, 1), (1, 1)],
-        "counts": [(R, 1)],
-    }[mode]
+    per_row = jax.ShapeDtypeStruct((Rb, 1, LANES), jnp.int32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(2 if mode in ("semicore+", "semicore*") else 1, nbk),
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec(s, const) for s in out_defs],
-        scratch_shapes=[
-            pltpu.VMEM((R, K), jnp.float32),    # capped-value histogram
-            pltpu.VMEM((R, 1), jnp.float32),    # phase-1 row accumulator
-            pltpu.VMEM((R, 1), jnp.int32),      # cap, then core2 / changed
-        ],
+        num_scalar_prefetch=2,
+        grid=(nbk,),
+        in_specs=[pl.BlockSpec((None, 1, cbe), stream),    # capped values
+                  pl.BlockSpec((None, 1, cbe), stream)],   # window rows
+        out_specs=[pl.BlockSpec((Rb, 1, LANES), whole),    # h
+                   pl.BlockSpec((Rb, 1, LANES), whole)],   # #(vals >= h)
+        scratch_shapes=[pltpu.VMEM((Rb, K, LANES), jnp.float32)],
     )
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(s, jnp.int32) for s in out_defs],
-        interpret=interpret)
+        kernel, grid_spec=grid_spec, out_shape=[per_row, per_row],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret, name="fused_superstep")
 
 
-def _compactify(x, arrs: FusedArrays):
-    c = jnp.where(arrs.validc,
-                  jnp.take(x, arrs.seg_of, mode="clip"), 0)
-    return c.astype(jnp.int32)[:, None]
+def _per_block(edge_on, dims):
+    cbe, nbk = dims[0], dims[1]
+    return jnp.any(edge_on.reshape(nbk, cbe), axis=1).astype(jnp.int32)
 
 
-def _globalize(xc, arrs: FusedArrays):
-    return jnp.where(arrs.present,
-                     jnp.take(xc[:, 0], arrs.rank, mode="clip"), 0)
+def _histogram(vals, block_on, arrs: FusedArrays, dims, num_probes,
+               interpret):
+    """(h, #(vals >= h)) per node, globalized (0 for absent nodes)."""
+    cbe, nbk = dims[0], dims[1]
+    fn = _hist_call(dims, int(num_probes), bool(interpret))
+    h, cnt = fn(block_on, arrs.base, vals.reshape(nbk, 1, cbe), arrs.local)
+
+    def globalize(x):
+        return jnp.where(arrs.present,
+                         jnp.take(x.reshape(-1), arrs.rank, mode="clip"), 0)
+
+    return globalize(h), globalize(cnt)
 
 
-def _scal_table(activec_b, arrs: FusedArrays, dims, phase1: bool):
-    cbe, Ep, nbk, U, R, n, E = dims
-    # act0[b]: any active rank in [firsts[b], lasts[b]] — exact, because
-    # every rank in a block's span has >= 1 edge in that block
-    s = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                         jnp.cumsum(activec_b.astype(jnp.int32))])
-    act0 = (jnp.take(s, arrs.lasts + 1) - jnp.take(s, arrs.firsts)) > 0
-    if phase1:
-        # act1[b]: any active *neighbor* in block b (sound superset for the
-        # changed-neighbor sweep: changed ⊆ active)
-        nbr_act = jnp.take(activec_b, arrs.nbrc[:, 0], mode="clip") & arrs.ev
-        act1 = jnp.any(nbr_act.reshape(nbk, cbe), axis=1)
-    else:
-        act1 = jnp.zeros((nbk,), dtype=bool)
-    return jnp.stack([act0.astype(jnp.int32), act1.astype(jnp.int32),
-                      arrs.firsts.astype(jnp.int32)])
+def _count(edge_on, block_on, arrs, dims, interpret):
+    """#(edges with ``edge_on``) per node: the histogram of {0, -1}."""
+    vals = jnp.where(edge_on, 0, -1).astype(jnp.int32)
+    return _histogram(vals, block_on, arrs, dims, 0, interpret)[1]
 
 
-def _invoke(mode, core, capsrc, active, arrs, dims, num_probes, interpret):
-    core_i = core.astype(jnp.int32)
-    nval = jnp.take(core_i, arrs.nbr, mode="clip")[:, None]
-    core0c = _compactify(capsrc, arrs)
-    activec_b = arrs.validc & jnp.take(active, arrs.seg_of, mode="clip")
-    activec = activec_b.astype(jnp.int32)[:, None]
-    scal = _scal_table(activec_b, arrs, dims,
-                       phase1=mode in ("semicore+", "semicore*"))
-    fn = _fused_call(dims, int(num_probes), mode, bool(interpret))
-    return fn(scal, core0c, activec, arrs.cptr, arrs.compact, arrs.nbrc,
-              nval)
+def _frontier_hindex(core, active, arrs, dims, num_probes, interpret):
+    """Capped h-index and #(nbr core >= h) of every active node (0 off the
+    frontier), plus the pass-start neighbour cores per edge."""
+    nv = jnp.take(core, arrs.nbr, mode="clip")
+    row_on = jnp.take(active, arrs.row, mode="clip") & arrs.ev
+    vals = jnp.where(
+        row_on, jnp.minimum(nv, jnp.take(core, arrs.row, mode="clip")), -1)
+    h, cnt_h = _histogram(vals, _per_block(row_on, dims), arrs, dims,
+                          num_probes, interpret)
+    return nv, h, cnt_h
 
 
 def fused_pass(core, cnt, active, arrs: FusedArrays, *, dims, num_probes,
                algorithm: str, interpret: bool):
-    """One engine superstep as ONE pallas_call; traceable (jit-safe).
+    """One engine superstep through the fused kernel; traceable (jit-safe).
 
     Args match the resident reference pass: ``core``/``cnt`` int32 (n,),
     ``active`` bool (n,).  Returns ``(core2, cnt2, active2, upd)`` with the
     exact semantics of the per-probe reference (resident.py) — including
     ``cnt``/``active`` passthrough for algorithms that don't track them.
     """
+    if algorithm not in ("semicore", "semicore+", "semicore*"):
+        raise ValueError(f"unknown algorithm {algorithm!r}")
     core = core.astype(jnp.int32)
-    outs = _invoke(algorithm, core, core, active, arrs, dims, num_probes,
-                   interpret)
+    nv, h, refr = _frontier_hindex(core, active, arrs, dims, num_probes,
+                                   interpret)
+    changed = active & (h != core)
+    core2 = jnp.where(active, h, core)
+    upd = jnp.sum(changed.astype(jnp.int32))
     if algorithm == "semicore":
-        h_c, upd = outs
-        core2 = jnp.where(active, _globalize(h_c, arrs), core)
-        return core2, cnt, active, upd[0, 0]
+        return core2, cnt, active, upd
+    # the neighbour rule only reads blocks holding an active neighbour
+    nbr_blocks = _per_block(
+        jnp.take(active, arrs.nbr, mode="clip") & arrs.ev, dims)
     if algorithm == "semicore+":
-        h_c, touched_c, upd = outs
-        h = _globalize(h_c, arrs)
-        core2 = jnp.where(active, h, core)
-        touched = _globalize(touched_c, arrs)
-        active2 = (touched > 0) & (core2 > 0)
-        return core2, cnt, active2, upd[0, 0]
-    if algorithm == "semicore*":
-        h_c, refr_c, dec_c, upd = outs
-        h = _globalize(h_c, arrs)
-        core2 = jnp.where(active, h, core)
-        cnt2 = jnp.where(active, _globalize(refr_c, arrs), cnt) \
-            - _globalize(dec_c, arrs)
-        active2 = (cnt2 < core2) & (core2 > 0)
-        return core2, cnt2, active2, upd[0, 0]
-    raise ValueError(f"unknown algorithm {algorithm!r}")
+        hit = jnp.take(changed, arrs.nbr, mode="clip") & arrs.ev
+        touched = _count(hit, nbr_blocks, arrs, dims, interpret)
+        return core2, cnt, (touched > 0) & (core2 > 0), upd
+    # push decrements: edge (v -> u) with u active and core2(v) in
+    # (h(u), c_old(u)]; an inactive neighbour has core2 == c_old, an empty
+    # interval
+    c2_row = jnp.take(core2, arrs.row, mode="clip")
+    push = arrs.ev & (c2_row > jnp.take(core2, arrs.nbr, mode="clip")) \
+        & (c2_row <= nv)
+    dec = _count(push, nbr_blocks, arrs, dims, interpret)
+    cnt2 = jnp.where(active, refr, cnt) - dec
+    return core2, cnt2, (cnt2 < core2) & (core2 > 0), upd
 
 
 @functools.partial(jax.jit, static_argnames=("dims", "num_probes",
@@ -461,22 +393,21 @@ def fused_hindex(core, active, arrs: FusedArrays, *, dims, num_probes,
     off-frontier.  PallasBackend serves ``compute_cnt(thresholds == h)``
     from the second output without another kernel launch.
     """
-    outs = _invoke("hindex", core, core, active, arrs, dims, num_probes,
-                   interpret)
-    h_c, refr_c, _upd = outs
-    return _globalize(h_c, arrs), _globalize(refr_c, arrs)
+    _, h, cnt_h = _frontier_hindex(core.astype(jnp.int32), active, arrs,
+                                   dims, num_probes, interpret)
+    return h, cnt_h
 
 
-@functools.partial(jax.jit, static_argnames=("dims", "num_probes",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("dims", "interpret"))
 def fused_counts(core, thresholds, active, arrs: FusedArrays, *, dims,
-                 num_probes, interpret):
-    """#(nbr pass-start core >= threshold) per active row, one call.
+                 interpret):
+    """#(nbr pass-start core >= threshold) per active row (0 elsewhere).
 
-    ``num_probes`` must satisfy ``2**num_probes >= max(thresholds) + 2``.
     Used by the warm-settle prologue and by ``compute_cnt`` calls whose
     thresholds differ from the pass's h (cache miss).
     """
-    (cnt_c,) = _invoke("counts", core, thresholds, active, arrs, dims,
-                       num_probes, interpret)
-    return _globalize(cnt_c, arrs)
+    row_on = jnp.take(active, arrs.row, mode="clip") & arrs.ev
+    ge = (jnp.take(core, arrs.nbr, mode="clip")
+          >= jnp.take(thresholds, arrs.row, mode="clip"))
+    return _count(row_on & ge, _per_block(row_on, dims), arrs, dims,
+                  interpret)

@@ -1,7 +1,8 @@
 """jit'd public wrappers around the Pallas kernels (+ XLA fallbacks).
 
-``use_pallas`` selects the kernel path; on this CPU container kernels run in
-interpret mode (the TPU lowering is the target, exercised by the dry-run).
+``use_pallas`` selects the kernel path.  The platform picks the mode
+(``kernels.resolve_interpret``): compiled by Mosaic on a TPU, the Pallas
+interpreter on CPU.
 """
 from __future__ import annotations
 

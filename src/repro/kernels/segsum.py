@@ -47,7 +47,7 @@ def segsum_pallas_partials(
 
     ``vals``    -- (E, D) float32, E a multiple of block_edges.
     ``compact`` -- (E, 1) int32 dense sorted segment ranks.
-    ``interpret`` -- None defers to ``kernels.default_interpret()``.
+    ``interpret`` -- None defers to ``kernels.resolve_interpret``.
     """
     from . import resolve_interpret
     interpret = resolve_interpret(interpret)

@@ -48,7 +48,7 @@ def segsum_active_partials(
 ) -> jax.Array:
     """Window partials like segsum, but inactive blocks are never fetched.
 
-    ``interpret`` -- None defers to ``kernels.default_interpret()``.
+    ``interpret`` -- None defers to ``kernels.resolve_interpret``.
     """
     from . import resolve_interpret
     interpret = resolve_interpret(interpret)

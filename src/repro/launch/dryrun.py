@@ -20,7 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .mesh import make_production_mesh, use_mesh
+from .mesh import make_production_mesh
 from .steps import build_step
 from ..configs import get_config, shape_names, ARCH_IDS
 
@@ -105,7 +105,7 @@ def _compile_bundle(bundle, mesh):
                      donate_argnums=bundle.donate_argnums)
     else:
         fn = bundle.fn  # already jit-wrapped (coregraph engine)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*bundle.args)
         compiled = lowered.compile()
     return compiled

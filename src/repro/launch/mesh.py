@@ -4,20 +4,6 @@ from __future__ import annotations
 import jax
 
 
-def use_mesh(mesh):
-    """Activate ``mesh`` for a ``with`` block, across jax versions.
-
-    ``jax.set_mesh`` only exists on newer jax; on the pinned 0.4.x line the
-    ``jax.sharding.Mesh`` object is itself the context manager that installs
-    the resource environment.  Both return a context manager, so call sites
-    are uniformly ``with use_mesh(mesh): ...``.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """256-chip pod mesh (data, model), or 512-chip 2-pod (pod, data, model).
 

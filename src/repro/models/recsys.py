@@ -71,7 +71,7 @@ def user_interests(params, cfg: RecsysConfig, hist_ids, profile_ids,
     flat = profile_ids.reshape(B * cfg.n_profile_fields, -1)
     bags = kops.embedding_bag(
         params["profile_embed"], flat, mode="mean",
-        use_pallas=use_pallas_bag, interpret=use_pallas_bag,
+        use_pallas=use_pallas_bag,
     ).reshape(B, cfg.n_profile_fields * D)
     prof = bags @ params["profile_proj"]  # (B, D)
     h = jnp.concatenate(

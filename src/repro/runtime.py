@@ -2,7 +2,7 @@
 
 Historically each subsystem read its own environment variable at its own
 call site (``engine.resolve_backend``, ``resident.resident_enabled``,
-``kernels.default_interpret``, ``fused_superstep.fused_enabled``,
+``kernels.resolve_interpret``, ``fused_superstep.fused_enabled``,
 ``obs.metrics.obs_enabled``, ...) with subtly different parsing rules.
 This module is now the single place those knobs are declared, parsed and
 resolved; the historical module-level functions remain as thin delegates.
@@ -22,8 +22,8 @@ Knobs
 ``device_resident``    ``REPRO_DEVICE_RESIDENT``    device-resident fixpoint (=0 off)
 ``resident_chunk``     ``REPRO_RESIDENT_CHUNK``     lax.scan passes per round-trip
 ``pallas_fused``       ``REPRO_PALLAS_FUSED``       fused single-kernel superstep
-``pallas_interpret``   ``REPRO_PALLAS_INTERPRET``   tri-state: None = auto by host
-``fused_block_edges``  ``REPRO_FUSED_BLOCK_EDGES``  kernel tile size (None = adapt)
+``pallas_interpret``   ``REPRO_PALLAS_INTERPRET``   must match the platform (CPU only)
+``fused_block_edges``  ``REPRO_FUSED_BLOCK_EDGES``  kernel tile size (None = 512)
 ``obs``                ``REPRO_OBS``                telemetry registry on/off
 ``parallel_maint``     ``REPRO_PARALLEL_MAINT``     grouped batched maintenance
 """
@@ -36,6 +36,7 @@ __all__ = [
     "Settings",
     "get_settings",
     "setting",
+    "enable_compile_cache",
     "ENV_VARS",
     "DEFAULT_RESIDENT_CHUNK",
 ]
@@ -125,8 +126,8 @@ class Settings:
     device_resident: bool = True
     resident_chunk: int = DEFAULT_RESIDENT_CHUNK
     pallas_fused: bool = True
-    pallas_interpret: bool | None = None  # None: auto (compiled on TPU/GPU)
-    fused_block_edges: int | None = None  # None: adapt to the graph
+    pallas_interpret: bool | None = None  # None: the platform decides
+    fused_block_edges: int | None = None  # None: the kernel's 512 tile
     obs: bool = True
     parallel_maint: bool = True
 
@@ -164,3 +165,21 @@ _DEFAULTS = {f.name: f.default for f in fields(Settings)}
 def get_settings(**overrides) -> Settings:
     """The module-level resolver: ``Settings.resolve`` with live env reads."""
     return Settings.resolve(**overrides)
+
+
+def enable_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and this
+    sets nothing; otherwise the cache lives at ``<root>/.jax_cache``, a
+    fixed path so that later runs of the same checkout find it.  Called by
+    the scripts (``chip_smoke.py``, ``benchmarks/*``), never on import.
+    Returns the cache directory.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+
+        path = os.path.join(os.path.abspath(root), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -18,7 +18,7 @@ from .replica import BootstrapStats, CoreReplica
 from .service import (BatchStats, CoreService, CoreWriter, EpochView,
                       QueryAPI, RecoveryStats, Watermarked, WatermarkedArray)
 from .wal import SnapshotStore, WalGap, WalTailer, WriteAheadLog
-from .workload import mixed_stream
+from .workload import mixed_stream, sampled_stream
 
 __all__ = [
     "Insert", "Delete", "UpdateBatch",
@@ -29,5 +29,5 @@ __all__ = [
     "QueryAPI", "RecoveryStats", "BootstrapStats",
     "Watermarked", "WatermarkedArray",
     "SnapshotStore", "WriteAheadLog", "WalTailer", "WalGap",
-    "mixed_stream",
+    "mixed_stream", "sampled_stream",
 ]

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["mixed_stream"]
+__all__ = ["mixed_stream", "sampled_stream"]
 
 
 def mixed_stream(g, num_updates: int, seed: int = 0, p_delete: float = 0.45):
@@ -36,3 +36,37 @@ def mixed_stream(g, num_updates: int, seed: int = 0, p_delete: float = 0.45):
             ordered.append((lo, hi))
             ops.append(("+", lo, hi))
     return ops, present
+
+
+def sampled_stream(g, num_updates: int, seed: int = 0,
+                   p_delete: float = 0.45):
+    """Return a mixed op list without materializing the edge set.
+
+    For graphs whose edge set does not fit a Python set: deletes draw a
+    uniform edge slot of the CSR and inserts a uniform non-edge, each
+    checked against the graph and against the ops drawn so far, so every
+    op changes the graph.  O(num_updates) memory beyond the graph.
+    """
+    rng = np.random.default_rng(seed)
+    deleted: set = set()
+    inserted: set = set()
+    ops = []
+    for _ in range(num_updates):
+        if rng.random() < p_delete:
+            while True:
+                slot = int(rng.integers(len(g.adj)))
+                u = int(np.searchsorted(g.indptr, slot, side="right")) - 1
+                e = tuple(sorted((u, int(g.adj[slot]))))
+                if e not in deleted:
+                    break
+            deleted.add(e)
+            ops.append(("-",) + e)
+        else:
+            while True:
+                u, v = int(rng.integers(g.n)), int(rng.integers(g.n))
+                e = (min(u, v), max(u, v))
+                if u != v and e not in inserted and not g.has_edge(*e):
+                    break
+            inserted.add(e)
+            ops.append(("+",) + e)
+    return ops

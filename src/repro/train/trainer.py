@@ -14,7 +14,7 @@ import numpy as np
 import jax
 
 from ..configs import get_config
-from ..launch.mesh import make_host_mesh, use_mesh
+from ..launch.mesh import make_host_mesh
 from ..launch.steps import build_step
 from ..optim import adamw_init
 from ..models.params import tree_init
@@ -146,7 +146,7 @@ class TrainLoop:
         prefetch = Prefetcher(source, start_step=start)
         losses = []
         t0 = time.time()
-        with use_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             for i in range(start, start + num_steps):
                 step_idx, batch = next(prefetch)
                 batch = jax.tree.map(jax.numpy.asarray, batch)

@@ -1,13 +1,7 @@
-"""Test bootstrap: make ``repro`` importable without PYTHONPATH tweaks and
-gate the optional ``hypothesis`` dev dependency behind a deterministic
-fallback (hermetic images cannot reach an index; see repro.compat)."""
+"""Test bootstrap: make ``repro`` importable without PYTHONPATH tweaks."""
 import os
 import sys
 
 _SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
-
-from repro.compat import install_hypothesis_fallback
-
-install_hypothesis_fallback()

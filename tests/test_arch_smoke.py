@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, shape_names, ARCH_IDS
-from repro.launch.mesh import make_host_mesh, use_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_step
 
 CELLS = []
@@ -88,7 +88,7 @@ def test_cell_smoke(arch, shape):
                   out_shardings=bundle.out_shardings,
                   donate_argnums=bundle.donate_argnums)
           if bundle.in_shardings is not None else bundle.fn)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = fn(*args)
 
     leaves = jax.tree.leaves(out)

@@ -1,4 +1,4 @@
-"""Fused single-kernel Pallas superstep (DESIGN.md §16).
+"""Fused Pallas superstep (DESIGN.md §16).
 
 Three layers of evidence that the one-pallas_call-per-pass path is exact:
 
@@ -14,17 +14,16 @@ Three layers of evidence that the one-pallas_call-per-pass path is exact:
 """
 import math
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-jnp = pytest.importorskip("jax.numpy")
-
-from repro.graph import paper_example_graph, chung_lu, erdos_renyi  # noqa: E402
-from repro.core.semicore import decompose  # noqa: E402
-from repro.kernels.fused_superstep import (  # noqa: E402
+from repro.graph import paper_example_graph, chung_lu, erdos_renyi
+from repro.core.semicore import decompose
+from repro.kernels.fused_superstep import (
     build_fused_table, fused_pass, fused_hindex, fused_counts,
     fused_block_edges)
-from repro.kernels.ref import fused_superstep_ref  # noqa: E402
+from repro.kernels.ref import fused_superstep_ref
 
 EXPECTED_CORES = np.array([3, 3, 3, 3, 2, 2, 2, 2, 1])
 ALGORITHMS = ("semicore", "semicore+", "semicore*")
@@ -113,21 +112,20 @@ def test_fused_hindex_and_counts_match_ref():
         want_cnt = np.zeros(n, np.int64)
         np.add.at(want_cnt, rows,
                   (core[nbr] >= thr[rows]).astype(np.int64))
-        tp = max(1, math.ceil(math.log2(int(thr.max()) + 2)))
         got_cnt = np.asarray(fused_counts(
             jnp.asarray(core), jnp.asarray(thr), jnp.asarray(active),
-            ft.arrays, dims=ft.dims, num_probes=tp, interpret=True))
+            ft.arrays, dims=ft.dims, interpret=True))
         np.testing.assert_array_equal(got_cnt[active], want_cnt[active])
 
 
 def test_adaptive_tile_default(monkeypatch):
     monkeypatch.delenv("REPRO_FUSED_BLOCK_EDGES", raising=False)
     assert fused_block_edges() == 512
-    assert fused_block_edges(12_000) == 512
-    assert fused_block_edges(26_000) == 2048
-    assert fused_block_edges(1_000_000) == 8192
     monkeypatch.setenv("REPRO_FUSED_BLOCK_EDGES", "64")
-    assert fused_block_edges(1_000_000) == 64
+    assert fused_block_edges() == 64
+    monkeypatch.setenv("REPRO_FUSED_BLOCK_EDGES", "4")
+    with pytest.raises(ValueError):
+        fused_block_edges()
 
 
 # ------------------------------------------------------- Fig. 2/4/5 pins

@@ -174,3 +174,35 @@ def test_segsum_active_localcore_frontier_semantics():
             for b in range(blk_lo, blk_hi + 1))
         if blocks_active:
             assert int(got[v]) == exact
+
+
+# ------------------------------------------------- interpret-mode policy
+def test_interpret_mode_follows_the_platform(monkeypatch):
+    """CPU interprets; a request for the other mode raises instead of
+    quietly emulating (or quietly compiling) on the wrong platform."""
+    from repro.kernels import resolve_interpret
+
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(True) is True
+    with pytest.raises(ValueError, match="interpret=False"):
+        resolve_interpret(False)
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    with pytest.raises(ValueError, match="interpret=False"):
+        resolve_interpret(None)
+
+
+def test_interpret_mode_is_refused_on_a_tpu(monkeypatch):
+    from repro.core.engine import resolve_backend
+    from repro.kernels import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    assert resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="'tpu'"):
+        resolve_interpret(True)
+    with pytest.raises(ValueError, match="'tpu'"):
+        resolve_backend("pallas-interpret")
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    with pytest.raises(ValueError, match="'tpu'"):
+        resolve_interpret(None)

@@ -405,3 +405,22 @@ def test_parallel_maint_env_toggle(monkeypatch):
     monkeypatch.setenv("REPRO_PARALLEL_MAINT", "1")
     s = m.apply(UpdateBatch.from_pairs([(0, 149)], []))
     assert s.algorithm.startswith("parallel(")
+
+
+def test_compile_cache_dir_env_wins_else_fixed_path(monkeypatch, tmp_path):
+    """Entry points keep JAX's compile cache where the env says, else at a
+    fixed path in the checkout (never a temporary or per-run name)."""
+    import jax
+
+    from repro.runtime import enable_compile_cache
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    assert enable_compile_cache(str(tmp_path)) == str(tmp_path / "env")
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = str(tmp_path / ".jax_cache")
+    assert enable_compile_cache(str(tmp_path)) == want
+    assert calls == [("jax_compilation_cache_dir", want)]

@@ -381,3 +381,25 @@ def test_watermark_service_replies_compose():
     assert (core >= 2).epoch == 1
     stale = _wm(np.asarray(core).copy(), epoch=0)
     assert (core - stale).epoch is None
+
+
+def test_sampled_stream_ops_are_valid_and_seeded():
+    """The edge-set-free generator: every delete names a base edge, every
+    insert a base non-edge, no edge twice, same seed same stream."""
+    from repro.stream import sampled_stream
+
+    g = chung_lu(400, 1600, seed=2)
+    ops = sampled_stream(g, 300, seed=7)
+    assert ops == sampled_stream(g, 300, seed=7)
+    assert ops != sampled_stream(g, 300, seed=8)
+    kinds = {k for k, _, _ in ops}
+    assert kinds == {"+", "-"}
+    seen = set()
+    for kind, u, v in ops:
+        assert u < v and (u, v) not in seen
+        seen.add((u, v))
+        assert g.has_edge(u, v) == (kind == "-")
+    svc = CoreService(g, backend="numpy")
+    for i in range(0, len(ops), 50):
+        st = svc.ingest(ops[i:i + 50])
+        assert st.num_noops == 0
