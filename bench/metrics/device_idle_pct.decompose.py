@@ -1,0 +1,7 @@
+"""Share of the decompose window in which no operation ran on the device,
+from the profiler trace."""
+from bench import readings
+
+
+def read(run):
+    return readings.idle_pct(run)
