@@ -1,0 +1,10 @@
+"""Per acknowledged batch, the time inside the program's
+``maintenance.parallel_settle`` spans that its ``resident.chunk`` spans do
+not cover: the settle's host work and, on the serial fallback that inserts
+take here, the exact-cnt prologue."""
+from bench import spanreads
+
+
+def read(run):
+    return spanreads.uncovered_per_unit_ms(
+        run, "maintenance.parallel_settle", "resident.chunk")

@@ -868,35 +868,40 @@ class PassPlanner:
         buffered = self.eng.buffered
         if buffered is None or not buffered._size:
             return nbr_flat, seg_ptr
-        dirty = np.fromiter(
-            buffered._ins.keys() | buffered._del.keys(), dtype=np.int64)
-        hit = np.flatnonzero(np.isin(nodes, dirty))
-        if not len(hit):
-            return nbr_flat, seg_ptr
-        merged = [
-            np.asarray(
-                buffered.merged_neighbors(
-                    int(nodes[i]), nbr_flat[seg_ptr[i]: seg_ptr[i + 1]]
-                ),
-                dtype=np.int32,
-            )
-            for i in hit
-        ]
-        new_lens = np.diff(seg_ptr)
-        new_lens[hit] = [len(s) for s in merged]
-        new_ptr = np.zeros(len(nodes) + 1, dtype=np.int64)
-        np.cumsum(new_lens, out=new_ptr[1:])
-        out = np.empty(int(new_ptr[-1]), dtype=np.int32)
-        prev_old = 0
-        prev_new = 0
-        for seg, i in zip(merged, hit):
-            span = int(seg_ptr[i]) - prev_old  # untouched run before i
-            out[prev_new: prev_new + span] = nbr_flat[prev_old: prev_old + span]
-            prev_new += span
-            out[prev_new: prev_new + len(seg)] = seg
-            prev_new += len(seg)
-            prev_old = int(seg_ptr[i + 1])
-        out[prev_new:] = nbr_flat[prev_old:]
+        with _trace.span("engine.merge_buffered", cat="engine",
+                         buffered=buffered._size) as sp:
+            dirty = np.fromiter(
+                buffered._ins.keys() | buffered._del.keys(), dtype=np.int64)
+            hit = np.flatnonzero(np.isin(nodes, dirty))
+            if sp.active:
+                sp.set(dirty=len(hit))
+            if not len(hit):
+                return nbr_flat, seg_ptr
+            merged = [
+                np.asarray(
+                    buffered.merged_neighbors(
+                        int(nodes[i]), nbr_flat[seg_ptr[i]: seg_ptr[i + 1]]
+                    ),
+                    dtype=np.int32,
+                )
+                for i in hit
+            ]
+            new_lens = np.diff(seg_ptr)
+            new_lens[hit] = [len(s) for s in merged]
+            new_ptr = np.zeros(len(nodes) + 1, dtype=np.int64)
+            np.cumsum(new_lens, out=new_ptr[1:])
+            out = np.empty(int(new_ptr[-1]), dtype=np.int32)
+            prev_old = 0
+            prev_new = 0
+            for seg, i in zip(merged, hit):
+                span = int(seg_ptr[i]) - prev_old  # untouched run before i
+                out[prev_new: prev_new + span] = \
+                    nbr_flat[prev_old: prev_old + span]
+                prev_new += span
+                out[prev_new: prev_new + len(seg)] = seg
+                prev_new += len(seg)
+                prev_old = int(seg_ptr[i + 1])
+            out[prev_new:] = nbr_flat[prev_old:]
         return out, new_ptr
 
     def full_structure(self):

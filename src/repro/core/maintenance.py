@@ -277,28 +277,31 @@ class CoreMaintainer:
                          path="parallel", backend=self.backend.name,
                          deletes=len(batch.deletes),
                          inserts=len(batch.inserts)) as sp:
-            for op in batch:
-                u, v = int(op.u), int(op.v)
-                if isinstance(op, Delete):
-                    if not self.bg.delete_edge(u, v):
-                        noop += 1
-                        continue
-                    nd += 1
-                    if core0[u] <= core0[v]:
-                        cnt[u] -= 1
-                    if core0[v] <= core0[u]:
-                        cnt[v] -= 1
-                    applied.append(("-", u, v))
-                else:
-                    if not self.bg.insert_edge(u, v):
-                        noop += 1
-                        continue
-                    ni += 1
-                    if core0[u] <= core0[v]:
-                        cnt[u] += 1
-                    if core0[v] <= core0[u]:
-                        cnt[v] += 1
-                    applied.append(("+", u, v))
+            with _trace.span("maint.apply_ops", cat="maintenance") as sp_ops:
+                for op in batch:
+                    u, v = int(op.u), int(op.v)
+                    if isinstance(op, Delete):
+                        if not self.bg.delete_edge(u, v):
+                            noop += 1
+                            continue
+                        nd += 1
+                        if core0[u] <= core0[v]:
+                            cnt[u] -= 1
+                        if core0[v] <= core0[u]:
+                            cnt[v] -= 1
+                        applied.append(("-", u, v))
+                    else:
+                        if not self.bg.insert_edge(u, v):
+                            noop += 1
+                            continue
+                        ni += 1
+                        if core0[u] <= core0[v]:
+                            cnt[u] += 1
+                        if core0[v] <= core0[u]:
+                            cnt[v] += 1
+                        applied.append(("+", u, v))
+                if sp_ops.active:
+                    sp_ops.set(applied=nd + ni, noops=noop)
             changed = 0
             groups = largest = fallbacks = passes = comp = 0
             if applied:

@@ -77,7 +77,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..obs import metrics as _metrics
+from ..obs import metrics as _metrics, trace as _trace
 from .engine import warm_settle
 
 __all__ = ["DEFAULT_GROUP_CAP", "UpdateCand", "BatchPlan", "plan_batch",
@@ -439,23 +439,36 @@ def grouped_settle(maintainer, applied, cap=DEFAULT_GROUP_CAP):
         info["fallback"] = True
         return r.core, r.cnt
 
-    arr = _Arrays(engine)  # the graph never changes during the settle
+    arr = None  # the graph never changes during the settle: built once
     risers = None  # round 1 plans from the updates; later from risers
     while True:
         info["rounds"] += 1
         core0 = maintainer.core
-        if risers is None:
-            plan = plan_batch(engine, core0, maintainer.cnt, applied, cap,
-                              arr=arr)
-        else:
-            plan = plan_risers(arr, core0, maintainer.cnt, risers, cap)
+        with _trace.span("maint.plan", cat="maintenance",
+                         round=info["rounds"]) as sp:
+            if arr is None:
+                arr = _Arrays(engine)
+            if risers is None:
+                plan = plan_batch(engine, core0, maintainer.cnt, applied,
+                                  cap, arr=arr)
+            else:
+                plan = plan_risers(arr, core0, maintainer.cnt, risers, cap)
+            heavy = plan.heavy or info["rounds"] > _MAX_ROUNDS
+            if plan.updates and not heavy:
+                warm, cnt, mask = _prep_state(arr, core0, maintainer.cnt,
+                                              plan.updates)
+                if sp.active:
+                    sp.set(masked=int(mask.sum()))
+            if sp.active:
+                sp.set(groups=len(plan.groups))
+        if risers is not None:
             if not plan.updates:
                 break
             info["reroots"] += len(plan.updates)
         summary.updates.extend(plan.updates)
         summary.groups.extend(plan.groups)
 
-        if plan.heavy or info["rounds"] > _MAX_ROUNDS:
+        if heavy:
             # a candidate component exceeded the size threshold: the
             # exact-cnt prologue + SemiCore* warm settle covers everything
             for g in plan.groups:
@@ -466,8 +479,6 @@ def grouped_settle(maintainer, applied, cap=DEFAULT_GROUP_CAP):
         for g in plan.groups:
             _GROUPS_SETTLED.inc()
 
-        warm, cnt, mask = _prep_state(arr, core0, maintainer.cnt,
-                                      plan.updates)
         if risers is not None and not np.any(warm > core0) \
                 and not np.any((cnt < warm) & (warm > 0) & mask):
             break  # re-root peeled to nothing: already saturated

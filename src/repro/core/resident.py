@@ -47,7 +47,7 @@ from functools import lru_cache
 import numpy as np
 
 from .. import runtime as _runtime
-from ..obs import trace as _trace
+from ..obs import metrics as _metrics, trace as _trace
 # registry series shared with the per-pass path: the replay increments the
 # exact counters engine.run_batch / PallasBackend.begin_pass would have
 from .engine import _KB_ACTIVE, _KB_SKIPPED, _MAINT_PROLOGUE, _pass_obs
@@ -78,15 +78,38 @@ CHUNK_ENV_VAR = "REPRO_RESIDENT_CHUNK"
 # ~2-4 chunks).  CoreGraphConfig.superstep_chunk / REPRO_RESIDENT_CHUNK tune.
 DEFAULT_CHUNK = 8
 
-# Incremented at *trace* time by every resident jit body: retraces — not
-# calls — bump it, so tests and the benchmark can count compiles per
-# decompose (the O(passes)-retrace regression guard).
-_TRACE_COUNT = [0]
+_TRACES = _metrics.counter(
+    "repro_resident_traces_total",
+    "Traces of the resident jit bodies (compiles, not calls), by function",
+)
+_H2D = _metrics.counter(
+    "repro_resident_h2d_bytes_total",
+    "Bytes of host arrays the resident runner turned into device arrays",
+)
+_H2D_EDGES = _H2D.labels(what="edge_table")
+_H2D_STATE = _H2D.labels(what="state")
+
+
+def _count_trace(fn: str) -> None:
+    """Called at *trace* time by every resident jit body ``fn``: retraces —
+    not calls — count, so tests and the benchmark can count compiles per
+    decompose (the O(passes)-retrace regression guard).  Unlike the other
+    series this one ignores ``REPRO_OBS=0``: the compile guards read it
+    whatever the kill switch says."""
+    _TRACES.labels(fn=fn).value += 1
 
 
 def trace_count() -> int:
     """Total resident-path jit traces so far in this process."""
-    return _TRACE_COUNT[0]
+    return int(_TRACES.value)
+
+
+def _h2d(arr: np.ndarray, series) -> np.ndarray:
+    """``arr`` unchanged, its ``nbytes`` added to an h2d ``series``: wraps
+    every host array the caller turns into a device array (the host copy is
+    counted; the device array is never read)."""
+    series.inc(arr.nbytes)
+    return arr
 
 
 def resident_enabled() -> bool:
@@ -214,6 +237,15 @@ def build_structure(planner) -> ResidentStructure:
     """Merged flat adjacency of all nodes, uploaded once (charge-free, like
     the per-pass pallas bind it replaces — disk I/O stays per-pass,
     replayed planner-side)."""
+    with _trace.span("resident.bind", cat="engine") as sp:
+        rs = _build_structure(planner)
+        if sp.active:
+            sp.set(E=rs.E, E_pad=rs.E_pad, bytes=rs.nbr_j.nbytes
+                   + rs.rows_j.nbytes + rs.segptr_j.nbytes)
+    return rs
+
+
+def _build_structure(planner) -> ResidentStructure:
     import jax.numpy as jnp
 
     planner.eng._sync()
@@ -243,9 +275,10 @@ def build_structure(planner) -> ResidentStructure:
         E_pad=E_pad,
         dmax=int(lens.max()) if len(lens) else 0,
         seg_ptr=np.asarray(seg_ptr, dtype=np.int64),
-        nbr_j=jnp.asarray(nbr),
-        rows_j=jnp.asarray(rows),
-        segptr_j=jnp.asarray(np.asarray(seg_ptr, dtype=np.int32)),
+        nbr_j=jnp.asarray(_h2d(nbr, _H2D_EDGES)),
+        rows_j=jnp.asarray(_h2d(rows, _H2D_EDGES)),
+        segptr_j=jnp.asarray(_h2d(np.asarray(seg_ptr, dtype=np.int32),
+                                  _H2D_EDGES)),
     )
 
 
@@ -288,6 +321,23 @@ def _substrate(kind: str, block_edges: int, interpret: bool):
     return for_pass
 
 
+def _program_name(base: str, algorithm: str | None, *tags: str) -> str:
+    """Stable name of one resident program variant, e.g.
+    ``chunk_semicore_star_masked``: jit calls it ``jit_<name>`` in the device
+    trace (the benchmark keys fixpoint time on the ``jit_chunk`` prefix), and
+    ``repro_resident_traces_total`` labels its traces with it."""
+    parts = [base]
+    if algorithm is not None:
+        parts.append(algorithm.replace("*", "_star").replace("+", "_plus"))
+    return "_".join(parts + [t for t in tags if t])
+
+
+def _named(fn, name: str):
+    """``fn`` renamed to ``name``, the name jit gives its program."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 @lru_cache(maxsize=None)
 def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
                fused: bool = False, masked: bool = False):
@@ -324,6 +374,8 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
     if masked and algorithm != "semicore*":
         raise ValueError("masked settle is a semicore* (cnt-gated) "
                          f"discipline; got {algorithm!r}")
+    name = _program_name("chunk", algorithm, "fused" if fused else "",
+                         "masked" if masked else "")
 
     if fused:
         from ..kernels import fused_superstep as fsk
@@ -331,7 +383,7 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
         if algorithm == "semicore":
             def chunk(core, done, arrs, *, num_probes, num_segments, chunk,
                       dims):
-                _TRACE_COUNT[0] += 1
+                _count_trace(name)
                 all_active = jnp.ones((num_segments,), jnp.bool_)
 
                 def run(args):
@@ -358,7 +410,7 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
         elif algorithm == "semicore+":
             def chunk(core, active, arrs, *, num_probes, num_segments, chunk,
                       dims):
-                _TRACE_COUNT[0] += 1
+                _count_trace(name)
 
                 def run(args):
                     core, active = args
@@ -412,21 +464,22 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
             if masked:
                 def chunk(core, cnt, active, cand, arrs, *, num_probes,
                           num_segments, chunk, dims):
-                    _TRACE_COUNT[0] += 1
+                    _count_trace(name)
                     return _scan_star(core, cnt, active, cand, arrs,
                                       num_probes, chunk, dims)
             else:
                 def chunk(core, cnt, active, arrs, *, num_probes,
                           num_segments, chunk, dims):
-                    _TRACE_COUNT[0] += 1
+                    _count_trace(name)
                     return _scan_star(core, cnt, active, None, arrs,
                                       num_probes, chunk, dims)
 
         else:
             raise ValueError(f"unknown algorithm {algorithm!r}")
 
-        return jax.jit(chunk, static_argnames=("num_probes", "num_segments",
-                                               "chunk", "dims"))
+        return jax.jit(_named(chunk, name),
+                       static_argnames=("num_probes", "num_segments", "chunk",
+                                        "dims"))
 
     for_pass = _substrate(kind, block_edges, interpret)
 
@@ -441,7 +494,7 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
         # every node, every pass; done after the first no-update pass
         def chunk(core, done, nbr, rows, segptr, *, num_probes, num_segments,
                   chunk):
-            _TRACE_COUNT[0] += 1
+            _count_trace(name)
             all_active = jnp.ones((num_segments,), jnp.bool_)
 
             def run(args):
@@ -464,14 +517,14 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
                 step, (core, done), None, length=chunk)
             return core, done, upds, ran
 
-        return jax.jit(chunk,
+        return jax.jit(_named(chunk, name),
                        static_argnames=("num_probes", "num_segments", "chunk"))
 
     if algorithm == "semicore+":
         # neighbors of changed nodes (Lemma 4.1), alive nodes only
         def chunk(core, active, nbr, rows, segptr, *, num_probes,
                   num_segments, chunk):
-            _TRACE_COUNT[0] += 1
+            _count_trace(name)
             row_sum = _sorted_segsum(segptr)
 
             def run(args):
@@ -501,7 +554,7 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
             done = ~jnp.any(active)
             return core, active, done, fronts, upds, ran
 
-        return jax.jit(chunk,
+        return jax.jit(_named(chunk, name),
                        static_argnames=("num_probes", "num_segments", "chunk"))
 
     if algorithm == "semicore*":
@@ -560,17 +613,17 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
         if masked:
             def chunk(core, cnt, active, cand, nbr, rows, segptr, *,
                       num_probes, num_segments, chunk):
-                _TRACE_COUNT[0] += 1
+                _count_trace(name)
                 return _scan_star(core, cnt, active, cand, nbr, rows, segptr,
                                   num_probes, num_segments, chunk)
         else:
             def chunk(core, cnt, active, nbr, rows, segptr, *, num_probes,
                       num_segments, chunk):
-                _TRACE_COUNT[0] += 1
+                _count_trace(name)
                 return _scan_star(core, cnt, active, None, nbr, rows, segptr,
                                   num_probes, num_segments, chunk)
 
-        return jax.jit(chunk,
+        return jax.jit(_named(chunk, name),
                        static_argnames=("num_probes", "num_segments", "chunk"))
 
     raise ValueError(f"unknown algorithm {algorithm!r}")
@@ -583,28 +636,31 @@ def _counts_all_fn(kind: str, block_edges: int, interpret: bool,
     import jax
     import jax.numpy as jnp
 
+    name = _program_name("counts_all", None, "fused" if fused else "")
     if fused:
         from ..kernels import fused_superstep as fsk
 
         def counts_all(core, arrs, *, num_segments, dims):
-            _TRACE_COUNT[0] += 1
+            _count_trace(name)
             all_active = jnp.ones((num_segments,), jnp.bool_)
             return fsk.fused_counts(core, core, all_active, arrs, dims=dims,
                                     interpret=interpret)
 
-        return jax.jit(counts_all, static_argnames=("num_segments", "dims"))
+        return jax.jit(_named(counts_all, name),
+                       static_argnames=("num_segments", "dims"))
 
     for_pass = _substrate(kind, block_edges, interpret)
 
     def counts_all(core, nbr, rows, segptr, *, num_segments):
-        _TRACE_COUNT[0] += 1
+        _count_trace(name)
         all_active = jnp.ones((num_segments,), jnp.bool_)
         segsum = for_pass(rows, segptr, all_active, num_segments)
         mask = jnp.ones(rows.shape, jnp.bool_)
         return fused_counts(core, nbr, rows, mask, core, num_segments,
                             segment_sum_fn=segsum)
 
-    return jax.jit(counts_all, static_argnames=("num_segments",))
+    return jax.jit(_named(counts_all, name),
+                   static_argnames=("num_segments",))
 
 
 # ===========================================================================
@@ -729,7 +785,7 @@ def run_resident(engine, algorithm: str, backend, *,
         core = engine.degrees().astype(np.int64)
     cmax = int(core.max()) if n else 0
     num_probes = max(1, int(np.ceil(np.log2(cmax + 2))))
-    core_j = jnp.asarray(core.astype(np.int32))
+    core_j = jnp.asarray(_h2d(core.astype(np.int32), _H2D_STATE))
 
     upd_hist: list = []
     comp_hist: list = []
@@ -781,7 +837,7 @@ def run_resident(engine, algorithm: str, backend, *,
             _MAINT_PROLOGUE.observe(time.perf_counter() - t0)
         elif warm:
             cnt = np.asarray(cnt, dtype=np.int64).copy()
-            cnt_j = jnp.asarray(cnt.astype(np.int32))
+            cnt_j = jnp.asarray(_h2d(cnt.astype(np.int32), _H2D_STATE))
         else:
             cnt = np.zeros(n, dtype=np.int64)
             cnt_j = jnp.zeros((n,), jnp.int32)
@@ -810,9 +866,10 @@ def run_resident(engine, algorithm: str, backend, *,
         fn = _chunk_fns(kind, be, interpret, algorithm, fused, masked)
         sargs, skw = substrate_args()
         if masked:
-            cand_j = jnp.asarray(np.asarray(settle_mask, dtype=bool))
+            cand_j = jnp.asarray(_h2d(np.asarray(settle_mask, dtype=bool),
+                                      _H2D_STATE))
             sargs = (cand_j,) + sargs
-        active_j = jnp.asarray(active0)
+        active_j = jnp.asarray(_h2d(active0, _H2D_STATE))
         while True:
             with _trace.span("resident.chunk", cat="engine",
                              algorithm="semicore*", backend=backend.name,
@@ -861,23 +918,9 @@ def run_resident(engine, algorithm: str, backend, *,
                     num_probes=num_probes, num_segments=n, chunk=chunk,
                     **skw)
                 ran = np.asarray(ran)
-                upds = np.asarray(upds)
-                for k in range(len(ran)):
-                    if not ran[k]:
-                        break
-                    iters += 1
-                    comp += n
-                    upd_hist.append(int(upds[k]))
-                    comp_hist.append(n)
-                    planner.charge_only(all_nodes)
-                    planner.account_node_scan(0, n - 1)
-                    _replay_kernel_blocks(tally, rs, be, nb, all_nodes)
-                    om[0].inc()
-                    om[1].inc(n)
-                    om[2].inc(int(upds[k]))
-                    _trace.instant("superstep.replay", cat="engine",
-                                   algorithm="semicore", index=iters,
-                                   frontier=n, updates=int(upds[k]))
+                iters, comp = _replay_all_nodes_chunk(
+                    planner, rs, be, nb, tally, np.asarray(upds), ran,
+                    upd_hist, comp_hist, iters, comp, om)
                 if sp.active:
                     sp.set(passes_run=int(ran.sum()))
             if bool(done_j):
@@ -916,23 +959,58 @@ def _replay_chunk(planner, rs, be, nb, tally, fronts, upds, ran,
     ``om`` is the (passes, frontier, updates) counter triple from
     :func:`engine._pass_obs`; the replayed per-pass markers are emitted as
     trace instants from the same pinned frontier masks the planner charges
-    come from, so tracing never perturbs the bit-identical guarantee."""
-    for k in range(len(ran)):
-        if not ran[k]:
-            break
-        frontier = np.flatnonzero(fronts[k]).astype(np.int64)
-        iters += 1
-        comp += len(frontier)
-        upd_hist.append(int(upds[k]))
-        comp_hist.append(int(len(frontier)))
-        _replay_pass(planner, frontier, tally, rs, be, nb)
-        if om is not None:
+    come from, so tracing never perturbs the bit-identical guarantee.  The
+    chunk's outputs are host arrays by now: the ``resident.replay`` span
+    times host work alone."""
+    with _trace.span("resident.replay", cat="engine") as sp:
+        iters0 = iters
+        for k in range(len(ran)):
+            if not ran[k]:
+                break
+            frontier = np.flatnonzero(fronts[k]).astype(np.int64)
+            iters += 1
+            comp += len(frontier)
+            upd_hist.append(int(upds[k]))
+            comp_hist.append(int(len(frontier)))
+            _replay_pass(planner, frontier, tally, rs, be, nb)
+            if om is not None:
+                om[0].inc()
+                om[1].inc(len(frontier))
+                om[2].inc(int(upds[k]))
+            _trace.instant("superstep.replay", cat="engine",
+                           algorithm=algorithm, index=iters,
+                           frontier=int(len(frontier)), updates=int(upds[k]))
+        if sp.active:
+            sp.set(passes=iters - iters0)
+    return iters, comp
+
+
+def _replay_all_nodes_chunk(planner, rs, be, nb, tally, upds, ran,
+                            upd_hist, comp_hist, iters, comp, om):
+    """:func:`_replay_chunk` for SemiCore, whose every pass scans all
+    nodes: no frontier masks come back, only the per-pass update counts."""
+    n = planner.n
+    all_nodes = np.arange(n, dtype=np.int64)
+    with _trace.span("resident.replay", cat="engine") as sp:
+        iters0 = iters
+        for k in range(len(ran)):
+            if not ran[k]:
+                break
+            iters += 1
+            comp += n
+            upd_hist.append(int(upds[k]))
+            comp_hist.append(n)
+            planner.charge_only(all_nodes)
+            planner.account_node_scan(0, n - 1)
+            _replay_kernel_blocks(tally, rs, be, nb, all_nodes)
             om[0].inc()
-            om[1].inc(len(frontier))
+            om[1].inc(n)
             om[2].inc(int(upds[k]))
-        _trace.instant("superstep.replay", cat="engine", algorithm=algorithm,
-                       index=iters, frontier=int(len(frontier)),
-                       updates=int(upds[k]))
+            _trace.instant("superstep.replay", cat="engine",
+                           algorithm="semicore", index=iters, frontier=n,
+                           updates=int(upds[k]))
+        if sp.active:
+            sp.set(passes=iters - iters0)
     return iters, comp
 
 
@@ -983,6 +1061,18 @@ def build_sharded_structure(planner, num_shards: int,
     (charge-free, like :func:`build_structure` — disk I/O stays per-pass,
     replayed planner-side).  ``devices`` pins the mesh to an explicit
     device list (default: the first ``num_shards`` visible devices)."""
+    with _trace.span("resident.bind", cat="engine", shards=num_shards) as sp:
+        ss = _build_sharded_structure(planner, num_shards, devices)
+        if sp.active:
+            sp.set(E=ss.E, E_pad=ss.E + ss.pad_edges, bytes=sum(
+                a.nbytes for a in (ss.dst_j, ss.rows_j, ss.emask_j,
+                                   ss.lseg_j, ss.owned_ids_j,
+                                   ss.owned_mask_j)))
+    return ss
+
+
+def _build_sharded_structure(planner, num_shards: int,
+                             devices) -> ShardedStructure:
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -996,6 +1086,10 @@ def build_sharded_structure(planner, num_shards: int,
     pool = list(devices) if devices is not None else jax.devices()
     mesh = Mesh(np.asarray(pool[:S]), ("shard",))
     sh = NamedSharding(mesh, P("shard"))
+
+    def put(arr):
+        return jax.device_put(_h2d(arr, _H2D_EDGES), sh)
+
     owned_flat = sg.owned_ids.reshape(-1).astype(np.int32)
     buffered = planner.eng.buffered
     return ShardedStructure(
@@ -1012,12 +1106,12 @@ def build_sharded_structure(planner, num_shards: int,
         pad_edges=int(sg.pad_edges),
         per_shard_edges=sg.per_shard_edges,
         mesh=mesh,
-        dst_j=jax.device_put(sg.dst, sh),
-        rows_j=jax.device_put(sg.rows, sh),
-        emask_j=jax.device_put(sg.edge_mask, sh),
-        lseg_j=jax.device_put(sg.lsegptr, sh),
-        owned_ids_j=jax.device_put(sg.owned_ids, sh),
-        owned_mask_j=jax.device_put(sg.owned_mask, sh),
+        dst_j=put(sg.dst),
+        rows_j=put(sg.rows),
+        emask_j=put(sg.edge_mask),
+        lseg_j=put(sg.lsegptr),
+        owned_ids_j=put(sg.owned_ids),
+        owned_mask_j=put(sg.owned_mask),
     )
 
 
@@ -1064,6 +1158,8 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
     axes = tuple(mesh.axis_names)
     shard = P(axes)
     repl = P()
+    name = _program_name("chunk", algorithm, "shard",
+                         "masked" if masked else "")
 
     def strip(*arrs):
         return tuple(a[0] for a in arrs)
@@ -1081,7 +1177,7 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
     if algorithm == "semicore":
         # every node, every pass; done after the first no-update pass
         def body(core, done, dst, rows, emask, lseg, owned_ids, owned_mask):
-            _TRACE_COUNT[0] += 1
+            _count_trace(name)
             dst, rows, emask, lseg, owned_ids, owned_mask = strip(
                 dst, rows, emask, lseg, owned_ids, owned_mask)
             segsum = _local_segsum(lseg)
@@ -1119,7 +1215,7 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
         # (core2 != core), so propagation is a local row reduction
         def body(core, active_b, nact, dst, rows, emask, lseg, owned_ids,
                  owned_mask):
-            _TRACE_COUNT[0] += 1
+            _count_trace(name)
             dst, rows, emask, lseg, owned_ids, owned_mask, active0 = strip(
                 dst, rows, emask, lseg, owned_ids, owned_mask, active_b)
             segsum = _local_segsum(lseg)
@@ -1167,7 +1263,7 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
         # ``masked`` adds a per-slot candidate operand ANDed into every
         # next frontier (the grouped-maintenance settle, DESIGN.md §18).
         def body(core, cnt_b, active_b, nact, *tail):
-            _TRACE_COUNT[0] += 1
+            _count_trace(name)
             if masked:
                 cand_b, dst, rows, emask, lseg, owned_ids, owned_mask = tail
                 (cand,) = strip(cand_b)
@@ -1235,7 +1331,7 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
     else:
         raise ValueError(f"unknown algorithm {algorithm!r}")
 
-    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+    sharded = jax.shard_map(_named(body, name), mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs, check_vma=False)
     return jax.jit(
         sharded,
@@ -1265,9 +1361,10 @@ def _shard_counts_fn(mesh, n: int):
     axes = tuple(mesh.axis_names)
     shard = P(axes)
     repl = P()
+    name = _program_name("counts_all", None, "shard")
 
     def body(core, dst, rows, emask, lseg, owned_ids, owned_mask):
-        _TRACE_COUNT[0] += 1
+        _count_trace(name)
         dst = dst[0]; rows = rows[0]; emask = emask[0]; lseg = lseg[0]
         owned_ids = owned_ids[0]; owned_mask = owned_mask[0]
         segsum = _local_segsum(lseg)
@@ -1279,7 +1376,7 @@ def _shard_counts_fn(mesh, n: int):
         return cnt[None]
 
     in_specs = (repl, shard, shard, shard, shard, shard, shard)
-    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+    sharded = jax.shard_map(_named(body, name), mesh=mesh, in_specs=in_specs,
                             out_specs=shard, check_vma=False)
     return jax.jit(
         sharded,
@@ -1476,22 +1573,9 @@ def run_sharded(engine, algorithm: str, backend, *,
                     core_j, done_j, ss.dst_j, ss.rows_j, ss.emask_j,
                     ss.lseg_j, ss.owned_ids_j, ss.owned_mask_j)
                 ran = np.asarray(ran)
-                upds = np.asarray(upds)
-                for k in range(len(ran)):
-                    if not ran[k]:
-                        break
-                    iters += 1
-                    comp += n
-                    upd_hist.append(int(upds[k]))
-                    comp_hist.append(n)
-                    planner.charge_only(all_nodes)
-                    planner.account_node_scan(0, n - 1)
-                    om[0].inc()
-                    om[1].inc(n)
-                    om[2].inc(int(upds[k]))
-                    _trace.instant("superstep.replay", cat="engine",
-                                   algorithm="semicore", index=iters,
-                                   frontier=n, updates=int(upds[k]))
+                iters, comp = _replay_all_nodes_chunk(
+                    planner, ss, 0, 0, None, np.asarray(upds), ran,
+                    upd_hist, comp_hist, iters, comp, om)
                 if sp.active:
                     sp.set(passes_run=int(ran.sum()))
             if bool(done_j) or budget_hit():

@@ -3,7 +3,8 @@
 Emits the JSON "trace event format" consumed by ``chrome://tracing`` and
 https://ui.perfetto.dev — complete events (``ph: "X"``) for timed spans and
 instant events (``ph: "i"``) for replayed per-pass markers.  Timestamps are
-microseconds relative to ``start_trace()``.
+microseconds relative to ``start_trace()``, stamped with
+``time.perf_counter()``.
 
 Design constraints, in order:
 
@@ -15,6 +16,25 @@ Design constraints, in order:
    no-op singleton unless a collector was started (``start_trace()`` or the
    ``REPRO_TRACE`` env var) *and* ``REPRO_OBS`` is not ``0``.  The fast path
    is one attribute read and one env check.
+3. **One clock with the device.**  While the collector is active, every span
+   also opens a ``jax.profiler.TraceAnnotation`` of the same name — when JAX
+   is already imported; this module never imports it.  Under a running JAX
+   profiler the span is then a host event of the profiler's own trace, on its
+   clock, beside the device operations (Perfetto, TensorBoard).
+
+Span catalogue (DESIGN.md §14 lists each with its args):
+
+* engine — ``superstep`` (per-pass loop), ``resident.chunk`` (one chunk
+  call of the resident fixpoint, its download and replay),
+  ``resident.replay`` (the host replay of one chunk's planner charges),
+  ``resident.bind`` (structure build and upload on a cache miss),
+  ``engine.merge_buffered`` (splice of buffered edge updates);
+* maintenance — ``maintenance.parallel_settle`` / ``.batch_settle`` /
+  ``.apply_batch`` (one micro-batch), ``maint.apply_ops`` (the
+  batch applied to the buffered graph), ``maint.plan`` (one round's grouped
+  planning), ``cnt_prologue`` (the exact-cnt scan);
+* stream — ``service.ingest``, ``wal.append``, ``wal.rotate``,
+  ``snapshot.save``, ``replica.bootstrap``, ``replica.sync``.
 
 ``REPRO_TRACE`` values: unset/``0`` — off; ``1`` — collect (caller saves);
 any other string — collect and atexit-save to that path.
@@ -24,6 +44,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import time
 from typing import List, Optional
 
@@ -69,10 +90,11 @@ class Span:
     """A timed complete event; use as a context manager.
 
     ``set(**args)`` attaches extra args visible in the Perfetto side panel
-    (frontier sizes, block activity, probe counts, …).
+    (frontier sizes, block activity, probe counts, …).  With JAX imported,
+    the span also opens a profiler ``TraceAnnotation`` of its name.
     """
 
-    __slots__ = ("_collector", "name", "cat", "args", "_t0")
+    __slots__ = ("_collector", "name", "cat", "args", "_t0", "_annotation")
     active = True
 
     def __init__(self, collector: "TraceCollector", name: str, cat: str, args: dict):
@@ -81,17 +103,25 @@ class Span:
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._annotation = None
 
     def set(self, **args) -> "Span":
         self.args.update(args)
         return self
 
     def __enter__(self) -> "Span":
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         self._collector._emit_complete(self)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
 
 
 class TraceCollector:

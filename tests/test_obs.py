@@ -414,3 +414,249 @@ def test_shared_bench_result_schema():
     assert out["derived"]["k"] == 1
     assert out["counters"]["repro_io_edge_block_reads_total"] == 10
     assert out["derived"]["passes_per_s"] == pytest.approx(1.0)
+
+
+# ================================== settle phase spans, h2d and trace counters
+SETTLE_CHILDREN = ("maint.apply_ops", "maint.plan", "resident.bind",
+                   "engine.merge_buffered", "resident.replay")
+
+
+def _undirected(g):
+    src = np.repeat(np.arange(g.n), np.diff(g.indptr))
+    dst = np.asarray(g.adj)
+    keep = src < dst
+    return src[keep], dst[keep]
+
+
+def _collect(fn):
+    """``fn()`` under the span collector; its complete events."""
+    trace_mod.clear_trace()
+    trace_mod.start_trace()
+    try:
+        fn()
+        return [e for e in trace_mod.get_collector().events
+                if e["ph"] == "X"]
+    finally:
+        trace_mod.stop_trace()
+        trace_mod.clear_trace()
+
+
+def _union_us(intervals) -> float:
+    total, end = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
+def test_settle_phase_spans_nest_and_cover_the_settle(tmp_path):
+    """On a CoreWriter over xla, the phase spans sit inside
+    ``maintenance.parallel_settle`` and, with ``resident.chunk``, cover at
+    least 90% of each settle: the host time of a batch has names."""
+    from repro.stream import CoreWriter
+
+    g = chung_lu(8000, 40000, seed=3)
+    r = decompose(g, "semicore*", "batch", backend="numpy")
+    w = CoreWriter(g, backend="xla", state=(r.core, r.cnt),
+                   wal_path=str(tmp_path / "writer.wal"))
+    src, dst = _undirected(g)
+    order = np.random.default_rng(0).permutation(len(src))
+    rng = np.random.default_rng(1)
+
+    def batch(i, inserts):
+        ops = [("-", int(src[j]), int(dst[j]))
+               for j in order[16 * i:16 * (i + 1)]]
+        for u, v in rng.integers(0, g.n, size=(inserts, 2)):
+            if u != v:
+                ops.append(("+", int(u), int(v)))
+        return ops
+
+    try:
+        w.ingest(batch(0, 0))  # compiles outside the traced batches
+        events = _collect(lambda: [w.ingest(batch(i, 4 * (i % 2)))
+                                   for i in range(1, 5)])
+    finally:
+        w.close()
+    settles = [e for e in events if e["name"] == "maintenance.parallel_settle"]
+    assert len(settles) == 4
+
+    def inside(e, s):
+        return s["ts"] <= e["ts"] and \
+            e["ts"] + e["dur"] <= s["ts"] + s["dur"]
+
+    names = {e["name"] for e in events}
+    for name in SETTLE_CHILDREN:
+        assert name in names, name
+    for e in events:
+        if e["name"] in SETTLE_CHILDREN + ("resident.chunk",):
+            assert any(inside(e, s) for s in settles), e["name"]
+    for s in settles:
+        covered = _union_us(
+            (e["ts"], e["ts"] + e["dur"]) for e in events
+            if e["name"] in SETTLE_CHILDREN + ("resident.chunk",)
+            and inside(e, s))
+        assert covered >= 0.9 * s["dur"], (covered, s["dur"])
+
+
+def test_build_structure_counts_edge_table_h2d_bytes():
+    from repro.core import resident
+    from repro.core.semicore import HostEngine
+
+    g = chung_lu(500, 2000, seed=4)
+    eng = HostEngine(g, 64)
+    before = get_registry().snapshot()
+    rs = resident.build_structure(eng.planner)
+    d = get_registry().delta(before)
+    assert rs.E_pad > rs.E  # the padding is counted: it is uploaded too
+    assert d['repro_resident_h2d_bytes_total{what="edge_table"}'] == \
+        2 * rs.E_pad * 4 + (g.n + 1) * 4
+    assert d.get('repro_resident_h2d_bytes_total{what="state"}', 0.0) == 0.0
+
+
+def test_spans_reach_the_jax_profiler_on_the_harness_clock(tmp_path):
+    """Every collector span is also a host event of the JAX profiler's
+    trace, and the benchmark harness's mapping of the collector's
+    ``perf_counter`` stamps (one offset, taken at a window annotation)
+    lands each within 1 ms of its profiler event."""
+    import glob
+    import time
+
+    import jax
+
+    from repro.core.maintenance import CoreMaintainer
+    from repro.core.update import Delete, UpdateBatch
+
+    g = chung_lu(2000, 8000, seed=2)
+    m = CoreMaintainer(g, backend="xla")
+    src, dst = _undirected(g)
+
+    def deletes(lo, hi):
+        return UpdateBatch(tuple(Delete(int(u), int(v))
+                                 for u, v in zip(src[lo:hi], dst[lo:hi])))
+
+    decompose(g, "semicore*", "batch", backend="xla")
+    m.apply(deletes(0, 20))  # compiles outside the profiled window
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    t_spans = time.perf_counter()
+    trace_mod.clear_trace()
+    trace_mod.start_trace()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        t_w0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("window"):
+            decompose(g, "semicore*", "batch", backend="xla")
+            m.apply(deletes(20, 40))
+    finally:
+        jax.profiler.stop_trace()
+        trace_mod.stop_trace()
+    spans = [e for e in trace_mod.get_collector().events if e["ph"] == "X"]
+    trace_mod.clear_trace()
+
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events: dict = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                events.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns))
+    (mark,) = events["window"]
+    offset = mark[0] - t_w0 * 1e9
+    assert {s["name"] for s in spans} >= {
+        "resident.chunk", "resident.replay", "resident.bind", "maint.plan",
+        "maint.apply_ops", "engine.merge_buffered"}
+    for name in {s["name"] for s in spans}:
+        assert len(events.get(name, ())) == sum(
+            1 for s in spans if s["name"] == name), name
+    for s in spans:
+        a = (t_spans + s["ts"] / 1e6) * 1e9 + offset
+        b = (t_spans + (s["ts"] + s["dur"]) / 1e6) * 1e9 + offset
+        err = min(max(abs(a - pa), abs(b - pb))
+                  for pa, pb in events[s["name"]])
+        assert err < 1e6, (s["name"], err)
+
+
+def test_shrinking_edge_bucket_retraces_once():
+    """Deletes that keep the padded edge table's shape retrace nothing;
+    the batch whose deletes take ``E`` below an ``_EDGE_BUCKET`` boundary
+    retraces the masked chunk program exactly once."""
+    from repro.core import resident
+    from repro.core.maintenance import CoreMaintainer
+    from repro.core.update import Delete, UpdateBatch
+
+    g = chung_lu(2000, 4300, seed=7)
+    m = CoreMaintainer(g, backend="xla")
+    src, dst = _undirected(g)
+    order = np.random.default_rng(0).permutation(len(src))
+    E = len(g.adj)
+    keep_above = (E - resident._EDGE_BUCKET) // 2 - 1
+    assert keep_above > 40
+    done = 0
+    for n_del, retraces in ((16, None), (16, 0), (keep_above - 30, 1)):
+        ops = tuple(Delete(int(src[j]), int(dst[j]))
+                    for j in order[done:done + n_del])
+        done += n_del
+        pad0 = m.backend._resident.E_pad
+        before = get_registry().snapshot()
+        count0 = resident.trace_count()
+        stats = m.apply(UpdateBatch(ops))
+        d = get_registry().delta(before)
+        assert stats.iterations > 0  # the masked fixpoint ran
+        crossed = m.backend._resident.E_pad < pad0
+        assert crossed == (retraces == 1)
+        if retraces is not None:
+            assert sum_by_name(d, "repro_resident_traces_total") == retraces
+            assert resident.trace_count() - count0 == retraces
+            if retraces:
+                assert d['repro_resident_traces_total'
+                         '{fn="chunk_semicore_star_masked"}'] == 1
+
+
+def test_trace_count_ignores_the_kill_switch(monkeypatch):
+    """``resident.trace_count()`` guards compile counts under ``REPRO_OBS=0``
+    too; the byte counters go quiet."""
+    from repro.core import resident
+
+    monkeypatch.setenv("REPRO_OBS", "0")
+    before = get_registry().snapshot()
+    count0 = resident.trace_count()
+    decompose(chung_lu(357, 1500, seed=9), "semicore*", "batch",
+              backend="xla")
+    assert resident.trace_count() > count0
+    assert sum_by_name(get_registry().delta(before),
+                       "repro_resident_h2d_bytes_total") == 0.0
+
+
+def test_no_profiler_annotation_when_collector_is_off(monkeypatch):
+    import jax
+
+    made = []
+
+    class Annotation:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    assert not trace_mod.tracing_active()
+    sp = trace_mod.span("off")
+    assert sp is trace_mod._NULL_SPAN
+    with sp:
+        pass
+    assert made == []
+    trace_mod.clear_trace()
+    trace_mod.start_trace()
+    try:
+        with trace_mod.span("on"):
+            pass
+    finally:
+        trace_mod.stop_trace()
+        trace_mod.clear_trace()
+    assert made == ["on"]
