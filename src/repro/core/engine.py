@@ -164,28 +164,34 @@ class DecompResult:
 # Shared jittable ops (consumed by XLABackend AND the SPMD engine)
 # ===========================================================================
 def edge_ge_counts(nbr_vals, rows, edge_mask, thresholds, num_segments,
-                   *, segment_sum_fn):
+                   *, segment_sum_fn, row_bcast_fn=None):
     """#{edges e : nbr_vals[e] >= thresholds[rows[e]]} per segment (Eq. 2).
 
     Traceable under jit; ``segment_sum_fn(vals, rows, num_segments)`` selects
     the reduction substrate (``jax.ops.segment_sum`` for XLA/SPMD, the Pallas
-    blocked kernel for the TPU path).
+    blocked kernel for the TPU path).  ``row_bcast_fn(x)`` gives ``x[rows]``
+    its own way (the resident xla table spreads it from segment offsets);
+    the default gathers by ``rows``.
     """
     import jax.numpy as jnp
 
-    ok = (nbr_vals >= jnp.take(thresholds, rows, mode="clip")) & edge_mask
+    thr = (jnp.take(thresholds, rows, mode="clip") if row_bcast_fn is None
+           else row_bcast_fn(thresholds))
+    ok = (nbr_vals >= thr) & edge_mask
     return segment_sum_fn(ok.astype(jnp.int32), rows, num_segments)
 
 
 def hindex_bsearch(nbr_vals, rows, edge_mask, c_old, num_probes,
-                   *, segment_sum_fn, unroll: bool = False):
+                   *, segment_sum_fn, unroll: bool = False,
+                   row_bcast_fn=None):
     """Vectorized binary search for h = max k <= c_old with count_ge(k) >= k.
 
     Exactly LocalCore (Eq. 1) capped at ``c_old``: count_ge is non-increasing
     in k, so the feasibility predicate is monotone and the search converges
     to ``min(h_index, c_old)`` in ``num_probes`` segment-sum scans.
     ``unroll`` expands the probe loop so cost analysis sees every scan
-    (REPRO_UNROLL_SCANS, launch/dryrun.py).
+    (REPRO_UNROLL_SCANS, launch/dryrun.py); ``row_bcast_fn`` as in
+    :func:`edge_ge_counts`.
     """
     import jax
     import jax.numpy as jnp
@@ -198,7 +204,8 @@ def hindex_bsearch(nbr_vals, rows, edge_mask, c_old, num_probes,
         lo, hi = state
         mid = (lo + hi + 1) // 2
         cnt = edge_ge_counts(nbr_vals, rows, edge_mask, mid, num_rows,
-                             segment_sum_fn=segment_sum_fn)
+                             segment_sum_fn=segment_sum_fn,
+                             row_bcast_fn=row_bcast_fn)
         ok = (cnt >= mid) & (mid > 0)
         return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)
 
@@ -617,7 +624,7 @@ class PallasBackend(DeviceBackend):
         self.seg_ptr = rs.seg_ptr  # flat-table offsets, for block coverage
         self.be = self._block_edges(planner)
         self.nb = -(-max(rs.E, 1) // self.be)
-        self._nbr_j, self._rows_j = rs.edge_table("pallas")
+        self._nbr_j, self._rows_j, _ = rs.edge_table("pallas")
 
     def unbind(self):
         # don't keep per-pass state alive on a long-lived maintainer between

@@ -8,7 +8,7 @@ the accelerator backends 20–100× slower than numpy in wall-clock despite
 walking identical passes.  This module is the fix (DESIGN.md §12):
 
 * **Residency** — ``core``, ``cnt``, the active/frontier mask, and the flat
-  edge table ``(nbr, rows)`` are uploaded once at bind.  The edge table is
+  edge table ``(nbr, segptr)`` are uploaded once at bind.  The edge table is
   cached in a :class:`ResidentStructure` keyed by the planner's structure
   token (base CSR identity + ``BufferedGraph.version``), so a long-lived
   ``CoreMaintainer`` re-binding after a no-op batch — or re-running on an
@@ -88,6 +88,19 @@ _H2D = _metrics.counter(
 )
 _H2D_EDGES = _H2D.labels(what="edge_table")
 _H2D_STATE = _H2D.labels(what="state")
+_ROW_BCAST = _metrics.counter(
+    "repro_resident_row_bcast_total",
+    "Node vectors spread to edge slots by the resident chunk programs, by "
+    "how: from segment offsets (segptr) or by a per-slot owner gather",
+)
+
+
+def _count_row_bcasts(series, ran, per_pass: int) -> None:
+    """Add the broadcasts of one chunk call's executed passes (``ran``, the
+    per-pass flags it returned) to ``series``; ``None`` (the fused pallas
+    kernel, which reads no per-slot owner) counts nothing."""
+    if series is not None:
+        series.inc(int(np.asarray(ran).sum()) * per_pass)
 
 
 def _count_trace(fn: str) -> None:
@@ -132,7 +145,7 @@ def chunk_len(explicit: int | None = None) -> int:
 # the resident superstep below and the SPMD engine's per-shard superstep.
 # ===========================================================================
 def fused_counts(core, dst, rows, edge_mask, thresholds, num_rows,
-                 *, segment_sum_fn):
+                 *, segment_sum_fn, row_bcast_fn=None):
     """#{edges (v,u) : core[u] >= thresholds[row(v)]} per row (Eq. 2)."""
     import jax.numpy as jnp
 
@@ -140,11 +153,11 @@ def fused_counts(core, dst, rows, edge_mask, thresholds, num_rows,
 
     return edge_ge_counts(
         jnp.take(core, dst, mode="clip"), rows, edge_mask, thresholds,
-        num_rows, segment_sum_fn=segment_sum_fn)
+        num_rows, segment_sum_fn=segment_sum_fn, row_bcast_fn=row_bcast_fn)
 
 
 def fused_hindex(core, dst, rows, edge_mask, c_old, num_probes,
-                 *, segment_sum_fn, unroll: bool = False):
+                 *, segment_sum_fn, unroll: bool = False, row_bcast_fn=None):
     """Binary-search h = max k <= c_old with count_ge(k) >= k (Eq. 1)."""
     import jax.numpy as jnp
 
@@ -152,7 +165,8 @@ def fused_hindex(core, dst, rows, edge_mask, c_old, num_probes,
 
     return hindex_bsearch(
         jnp.take(core, dst, mode="clip"), rows, edge_mask, c_old, num_probes,
-        segment_sum_fn=segment_sum_fn, unroll=unroll)
+        segment_sum_fn=segment_sum_fn, unroll=unroll,
+        row_bcast_fn=row_bcast_fn)
 
 
 # ===========================================================================
@@ -174,11 +188,10 @@ class ResidentStructure:
     dmax: int                # max merged degree (pallas float32-range check)
     seg_ptr: np.ndarray      # (n+1,) int64 flat-table offsets, host
     nbr_j: object            # (E_pad,) int32 device — edge targets
-    rows_j: object           # (E_pad,) int32 device — edge source per slot
     segptr_j: object         # (n+1,) int32 device — flat-table offsets
     E_pad: int = 0           # bucket-padded device length (>= E)
     fused_tables: dict = field(default_factory=dict)
-    trimmed: tuple | None = None  # cached (nbr, rows) exact-E device views
+    pallas_table: tuple | None = None  # cached (nbr, rows) exact-E views
 
     def matches(self, planner) -> bool:
         buffered = planner.eng.buffered
@@ -200,21 +213,32 @@ class ResidentStructure:
             self.fused_tables[block_edges] = ft
         return ft
 
-    def edge_table(self, kind: str):
-        """(nbr, rows) device arrays for one substrate.
+    def edge_table(self, kind: str) -> tuple:
+        """The table operands of one substrate's chunk programs.
 
-        The xla substrate reduces edges exclusively through segptr-bounded
-        prefix sums (:func:`_sorted_segsum`), so it takes the bucket-padded
-        table as-is: the padded tail can never reach a segment sum, and the
-        stable shape keeps the chunk jits cached across structural versions
-        (the maintenance hot loop would otherwise recompile on every edge
-        insert/delete).  The pallas blocked kernels scatter by edge slot and
-        get the exact-length view instead."""
-        if kind != "pallas" or self.E == self.E_pad:
-            return self.nbr_j, self.rows_j
-        if self.trimmed is None:
-            self.trimmed = (self.nbr_j[:self.E], self.rows_j[:self.E])
-        return self.trimmed
+        xla: ``(nbr, segptr)``.  It reduces edges exclusively through
+        segptr-bounded prefix sums (:func:`_sorted_segsum`) and spreads node
+        values to edge slots from segptr (:func:`_row_bcast`), so it needs
+        no per-slot owner table and takes the bucket-padded ``nbr`` as-is:
+        the padded tail can never reach a segment sum, and the stable shape
+        keeps the chunk jits cached across structural versions (the
+        maintenance hot loop would otherwise recompile on every edge
+        insert/delete).
+
+        pallas: ``(nbr, rows, segptr)``, exact length.  Its blocked kernels
+        scatter by edge slot, so they need ``rows`` (each slot's owner);
+        that is built and uploaded on the first request and cached for the
+        structure's lifetime."""
+        if kind != "pallas":
+            return self.nbr_j, self.segptr_j
+        if self.pallas_table is None:
+            import jax.numpy as jnp
+
+            rows = np.repeat(np.arange(self.n, dtype=np.int32),
+                             np.diff(self.seg_ptr))
+            nbr = self.nbr_j if self.E == self.E_pad else self.nbr_j[:self.E]
+            self.pallas_table = (nbr, jnp.asarray(_h2d(rows, _H2D_EDGES)))
+        return self.pallas_table + (self.segptr_j,)
 
 
 _EDGE_BUCKET = 8192
@@ -240,8 +264,8 @@ def build_structure(planner) -> ResidentStructure:
     with _trace.span("resident.bind", cat="engine") as sp:
         rs = _build_structure(planner)
         if sp.active:
-            sp.set(E=rs.E, E_pad=rs.E_pad, bytes=rs.nbr_j.nbytes
-                   + rs.rows_j.nbytes + rs.segptr_j.nbytes)
+            sp.set(E=rs.E, E_pad=rs.E_pad,
+                   bytes=rs.nbr_j.nbytes + rs.segptr_j.nbytes)
     return rs
 
 
@@ -264,8 +288,6 @@ def _build_structure(planner) -> ResidentStructure:
     E_pad = _edge_pad(E)
     nbr = np.zeros(E_pad, dtype=np.int32)
     nbr[:E] = nbr_flat
-    rows = np.zeros(E_pad, dtype=np.int32)
-    rows[:E] = np.repeat(np.arange(n, dtype=np.int64), lens)
     buffered = planner.eng.buffered
     return ResidentStructure(
         graph=planner.eng.graph,
@@ -276,7 +298,6 @@ def _build_structure(planner) -> ResidentStructure:
         dmax=int(lens.max()) if len(lens) else 0,
         seg_ptr=np.asarray(seg_ptr, dtype=np.int64),
         nbr_j=jnp.asarray(_h2d(nbr, _H2D_EDGES)),
-        rows_j=jnp.asarray(_h2d(rows, _H2D_EDGES)),
         segptr_j=jnp.asarray(_h2d(np.asarray(seg_ptr, dtype=np.int32),
                                   _H2D_EDGES)),
     )
@@ -301,23 +322,54 @@ def _sorted_segsum(segptr):
     return segsum
 
 
+def _row_bcast(segptr, num_slots: int):
+    """``x[rows]`` for the resident table's *sorted* rows, from ``segptr``
+    alone: scatter-add the n differences ``x[v] - x[v-1]`` at ``segptr[v]``
+    (sorted, not unique: empty segments share a start), then one cumsum over
+    the slots — an n-element scatter and a prefix sum in place of an
+    E-element gather.  Exact: integer adds, and the wrap-around of a
+    difference cancels in the sum.  Slots ``>= E`` (the bucket padding) get
+    whatever value results; they never reach a segment sum, which
+    :func:`_sorted_segsum` bounds by ``segptr``."""
+    import jax.numpy as jnp
+
+    def bcast(x):
+        step = x - jnp.concatenate([jnp.zeros((1,), x.dtype), x[:-1]])
+        marks = jnp.zeros((num_slots,), x.dtype).at[segptr[:-1]].add(
+            step, mode="drop", indices_are_sorted=True)
+        return jnp.cumsum(marks)
+
+    return bcast
+
+
 def _substrate(kind: str, block_edges: int, interpret: bool):
-    """segment_sum_fn factory: given the pass's structure + activity mask,
-    return the (vals, rows, num_segments) reduction the shared probe ops
-    consume — the blocked DMA-skipping kernel for pallas, the sorted
-    prefix-sum reduction for xla."""
+    """How one substrate's chunk programs read the resident table.
+
+    Returns ``for_pass(table, node_active, num_segments) -> (nbr, rows,
+    segsum, bcast)`` for the operands ``ResidentStructure.edge_table(kind)``
+    gives: ``segsum`` is the (vals, rows, num_segments) reduction the shared
+    probe ops consume, ``bcast(x)`` spreads a node vector to the edge slots
+    (``x[rows]``).  pallas: the blocked DMA-skipping kernel and a gather by
+    ``rows``; xla: the sorted prefix-sum reduction and :func:`_row_bcast`,
+    with no ``rows`` at all."""
+    import jax.numpy as jnp
+
     if kind == "pallas":
         from ..kernels.ops import make_superstep_segsum
 
-        def for_pass(rows, segptr, node_active, num_segments):
+        def for_pass(table, node_active, num_segments):
+            nbr, rows, _ = table
             apply_ = make_superstep_segsum(
                 rows, node_active, num_segments,
                 block_edges=block_edges, interpret=interpret)
-            return lambda vals, _rows, _ns: apply_(vals)
+            return (nbr, rows, lambda vals, _rows, _ns: apply_(vals),
+                    lambda x: jnp.take(x, rows, mode="clip"))
     else:
-        def for_pass(rows, segptr, node_active, num_segments):
+        def for_pass(table, node_active, num_segments):
+            nbr, segptr = table
             apply_ = _sorted_segsum(segptr)
-            return lambda vals, _rows, _ns: apply_(vals)
+            return (nbr, None, lambda vals, _rows, _ns: apply_(vals),
+                    _row_bcast(segptr, nbr.shape[0]))
     return for_pass
 
 
@@ -358,8 +410,9 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
     Node-state bookkeeping that scatters along unsorted ``nbr`` (the push
     rule, changed-neighbor propagation) is rewritten through the undirected
     symmetry — edge (v→u) exists iff (u→v) does — as a *sorted* row
-    reduction, so the whole superstep runs scatter-free (prefix sums +
-    gathers; XLA CPU scatters would serialize it).
+    reduction, so no E-sized scatter is left (prefix sums + gathers; XLA
+    CPU scatters would serialize it): the one scatter, the row broadcast's
+    (:func:`_row_bcast`), writes n elements.
 
     With ``fused`` (the pallas hot path, DESIGN.md §16) each superstep is
     one ``kernels.fused_superstep.fused_pass`` (one histogram kernel call,
@@ -481,26 +534,28 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
                        static_argnames=("num_probes", "num_segments", "chunk",
                                         "dims"))
 
+    # the non-fused chunks take the substrate's table operands positionally
+    # (``*table``): ``(nbr, segptr)`` for xla, ``(nbr, rows, segptr)`` for
+    # pallas — ResidentStructure.edge_table
     for_pass = _substrate(kind, block_edges, interpret)
 
-    def hindex_pass(core, active, nbr, rows, segptr, num_probes, n):
-        segsum = for_pass(rows, segptr, active, n)
-        mask = jnp.ones(rows.shape, jnp.bool_)
+    def hindex_pass(core, active, table, num_probes, n):
+        nbr, rows, segsum, bcast = for_pass(table, active, n)
+        mask = jnp.ones(nbr.shape, jnp.bool_)
         c_old = jnp.where(active, core, 0)
         return fused_hindex(core, nbr, rows, mask, c_old, num_probes,
-                            segment_sum_fn=segsum)
+                            segment_sum_fn=segsum, row_bcast_fn=bcast)
 
     if algorithm == "semicore":
         # every node, every pass; done after the first no-update pass
-        def chunk(core, done, nbr, rows, segptr, *, num_probes, num_segments,
-                  chunk):
+        def chunk(core, done, *table, num_probes, num_segments, chunk):
             _count_trace(name)
             all_active = jnp.ones((num_segments,), jnp.bool_)
 
             def run(args):
                 core, _ = args
-                h = hindex_pass(core, all_active, nbr, rows, segptr,
-                                num_probes, num_segments)
+                h = hindex_pass(core, all_active, table, num_probes,
+                                num_segments)
                 upd = jnp.sum((h != core).astype(jnp.int32))
                 return (h, upd == 0), upd
 
@@ -522,14 +577,14 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
 
     if algorithm == "semicore+":
         # neighbors of changed nodes (Lemma 4.1), alive nodes only
-        def chunk(core, active, nbr, rows, segptr, *, num_probes,
-                  num_segments, chunk):
+        def chunk(core, active, *table, num_probes, num_segments, chunk):
             _count_trace(name)
+            nbr, segptr = table[0], table[-1]
             row_sum = _sorted_segsum(segptr)
 
             def run(args):
                 core, active = args
-                h = hindex_pass(core, active, nbr, rows, segptr, num_probes,
+                h = hindex_pass(core, active, table, num_probes,
                                 num_segments)
                 changed = active & (h != core)
                 core2 = jnp.where(active, h, core)
@@ -561,30 +616,32 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
         # cnt-gated (Lemma 4.2) with exact cnt maintenance under
         # simultaneous updates: refresh vs pass-start values, then the
         # UpdateNbrCnt push rule (DESIGN.md §2) — all on device
-        def _scan_star(core, cnt, active, cand, nbr, rows, segptr,
-                       num_probes, num_segments, chunk):
-            row_sum = _sorted_segsum(segptr)
+        def _scan_star(core, cnt, active, cand, table, num_probes,
+                       num_segments, chunk):
+            row_sum = _sorted_segsum(table[-1])
 
             def run(args):
                 core, cnt, active = args
-                segsum = for_pass(rows, segptr, active, num_segments)
-                mask = jnp.ones(rows.shape, jnp.bool_)
+                nbr, rows, segsum, bcast = for_pass(table, active,
+                                                    num_segments)
+                mask = jnp.ones(nbr.shape, jnp.bool_)
                 nbr_vals = jnp.take(core, nbr, mode="clip")  # pass-start
                 c_old = jnp.where(active, core, 0)
                 from .engine import edge_ge_counts, hindex_bsearch
                 h = hindex_bsearch(nbr_vals, rows, mask, c_old, num_probes,
-                                   segment_sum_fn=segsum)
+                                   segment_sum_fn=segsum, row_bcast_fn=bcast)
                 upd = jnp.sum((active & (h != core)).astype(jnp.int32))
                 core2 = jnp.where(active, h, core)
                 # (1) recompute cnt of the frontier vs pass-start values
                 thr = jnp.where(active, h, 0)
                 refreshed = edge_ge_counts(nbr_vals, rows, mask, thr,
                                            num_segments,
-                                           segment_sum_fn=segsum)
+                                           segment_sum_fn=segsum,
+                                           row_bcast_fn=bcast)
                 # (2) push decrements: dec[u] = #{edges (v in F -> u) :
                 #     core_now(u) in (h(v), c_old(v)]} — by symmetry summed
                 #     over u's own sorted segment, v = nbr[e]
-                core2_row = jnp.take(core2, rows, mode="clip")
+                core2_row = bcast(core2)
                 act_nbr = jnp.take(active, nbr, mode="clip")
                 h_nbr = jnp.take(h, nbr, mode="clip")
                 c_old_nbr = jnp.take(core, nbr, mode="clip")
@@ -611,17 +668,17 @@ def _chunk_fns(kind: str, block_edges: int, interpret: bool, algorithm: str,
             return core, cnt, active, done, fronts, upds, ran
 
         if masked:
-            def chunk(core, cnt, active, cand, nbr, rows, segptr, *,
-                      num_probes, num_segments, chunk):
-                _count_trace(name)
-                return _scan_star(core, cnt, active, cand, nbr, rows, segptr,
-                                  num_probes, num_segments, chunk)
-        else:
-            def chunk(core, cnt, active, nbr, rows, segptr, *, num_probes,
+            def chunk(core, cnt, active, cand, *table, num_probes,
                       num_segments, chunk):
                 _count_trace(name)
-                return _scan_star(core, cnt, active, None, nbr, rows, segptr,
-                                  num_probes, num_segments, chunk)
+                return _scan_star(core, cnt, active, cand, table, num_probes,
+                                  num_segments, chunk)
+        else:
+            def chunk(core, cnt, active, *table, num_probes, num_segments,
+                      chunk):
+                _count_trace(name)
+                return _scan_star(core, cnt, active, None, table, num_probes,
+                                  num_segments, chunk)
 
         return jax.jit(_named(chunk, name),
                        static_argnames=("num_probes", "num_segments", "chunk"))
@@ -651,13 +708,13 @@ def _counts_all_fn(kind: str, block_edges: int, interpret: bool,
 
     for_pass = _substrate(kind, block_edges, interpret)
 
-    def counts_all(core, nbr, rows, segptr, *, num_segments):
+    def counts_all(core, *table, num_segments):
         _count_trace(name)
         all_active = jnp.ones((num_segments,), jnp.bool_)
-        segsum = for_pass(rows, segptr, all_active, num_segments)
-        mask = jnp.ones(rows.shape, jnp.bool_)
+        nbr, rows, segsum, bcast = for_pass(table, all_active, num_segments)
+        mask = jnp.ones(nbr.shape, jnp.bool_)
         return fused_counts(core, nbr, rows, mask, core, num_segments,
-                            segment_sum_fn=segsum)
+                            segment_sum_fn=segsum, row_bcast_fn=bcast)
 
     return jax.jit(_named(counts_all, name),
                    static_argnames=("num_segments",))
@@ -767,16 +824,15 @@ def run_resident(engine, algorithm: str, backend, *,
     else:
         fused = False
 
-    nbr_j, rows_j = rs.edge_table(kind)
-
     def substrate_args():
         """Positional + static-kw tail of the chunk fns for this substrate:
         the fused path ships the compact-rank kernel table, the per-probe
-        paths the flat edge table (bucket-padded for xla, exact for pallas)."""
+        paths the flat edge table (bucket-padded ``(nbr, segptr)`` for xla,
+        exact ``(nbr, rows, segptr)`` for pallas)."""
         if fused:
             ft = rs.fused(fsk.fused_block_edges())
             return (ft.arrays,), {"dims": ft.dims}
-        return (nbr_j, rows_j, rs.segptr_j), {}
+        return rs.edge_table(kind), {}
 
     warm = core is not None
     if warm:
@@ -786,6 +842,11 @@ def run_resident(engine, algorithm: str, backend, *,
     cmax = int(core.max()) if n else 0
     num_probes = max(1, int(np.ceil(np.log2(cmax + 2))))
     core_j = jnp.asarray(_h2d(core.astype(np.int32), _H2D_STATE))
+    # node vectors spread to edge slots: one a probe, plus the cnt refresh
+    # and the push rule's core in a SemiCore* pass
+    bcasts = None if fused else _ROW_BCAST.labels(
+        how="segptr" if kind == "xla" else "gather")
+    per_pass = num_probes + (2 if algorithm == "semicore*" else 0)
 
     upd_hist: list = []
     comp_hist: list = []
@@ -823,14 +884,12 @@ def run_resident(engine, algorithm: str, backend, *,
                 planner.charge_only(all_nodes)
                 planner.account_node_scan(0, n - 1)
                 _replay_kernel_blocks(tally, rs, be, nb, all_nodes)
-                if rs.E and fused:
-                    counts_all = _counts_all_fn(kind, be, interpret, True)
+                if rs.E:
+                    counts_all = _counts_all_fn(kind, be, interpret, fused)
                     sargs, skw = substrate_args()
                     cnt_j = counts_all(core_j, *sargs, num_segments=n, **skw)
-                elif rs.E:
-                    counts_all = _counts_all_fn(kind, be, interpret)
-                    cnt_j = counts_all(core_j, nbr_j, rows_j,
-                                       rs.segptr_j, num_segments=n)
+                    if bcasts is not None:
+                        bcasts.inc()
                 else:
                     cnt_j = jnp.zeros((n,), jnp.int32)
                 cnt = np.asarray(cnt_j, dtype=np.int64)
@@ -882,6 +941,7 @@ def run_resident(engine, algorithm: str, backend, *,
                     planner, rs, be, nb, tally, np.asarray(fronts),
                     np.asarray(upds), np.asarray(ran), upd_hist, comp_hist,
                     iters, comp, om, "semicore*")
+                _count_row_bcasts(bcasts, ran, per_pass)
                 if sp.active:
                     sp.set(passes_run=int(np.asarray(ran).sum()))
             if bool(done):
@@ -921,6 +981,7 @@ def run_resident(engine, algorithm: str, backend, *,
                 iters, comp = _replay_all_nodes_chunk(
                     planner, rs, be, nb, tally, np.asarray(upds), ran,
                     upd_hist, comp_hist, iters, comp, om)
+                _count_row_bcasts(bcasts, ran, per_pass)
                 if sp.active:
                     sp.set(passes_run=int(ran.sum()))
             if bool(done_j):
@@ -943,6 +1004,7 @@ def run_resident(engine, algorithm: str, backend, *,
                     planner, rs, be, nb, tally, np.asarray(fronts),
                     np.asarray(upds), np.asarray(ran), upd_hist, comp_hist,
                     iters, comp, om, "semicore+")
+                _count_row_bcasts(bcasts, ran, per_pass)
                 if sp.active:
                     sp.set(passes_run=int(np.asarray(ran).sum()))
             if bool(done):
@@ -1422,6 +1484,8 @@ def run_sharded(engine, algorithm: str, backend, *,
     cmax = int(core.max()) if n else 0
     num_probes = max(1, int(np.ceil(np.log2(cmax + 2))))
     core_j = jnp.asarray(core.astype(np.int32))
+    bcasts = _ROW_BCAST.labels(how="gather")  # per-shard rows, as flat xla
+    per_pass = num_probes + (2 if algorithm == "semicore*" else 0)
 
     upd_hist: list = []
     comp_hist: list = []
@@ -1493,6 +1557,7 @@ def run_sharded(engine, algorithm: str, backend, *,
                     counts = _shard_counts_fn(ss.mesh, n)
                     cnt_lj = counts(core_j, ss.dst_j, ss.rows_j, ss.emask_j,
                                     ss.lseg_j, ss.owned_ids_j, ss.owned_mask_j)
+                    bcasts.inc()
                     cnt = globalize(cnt_lj, 0, np.int64)
                 else:
                     cnt = np.zeros(n, dtype=np.int64)
@@ -1541,6 +1606,7 @@ def run_sharded(engine, algorithm: str, backend, *,
                     planner, ss, 0, 0, None, front_masks(fronts),
                     np.asarray(upds), np.asarray(ran), upd_hist, comp_hist,
                     iters, comp, om, "semicore*")
+                _count_row_bcasts(bcasts, ran, per_pass)
                 if sp.active:
                     sp.set(passes_run=int(np.asarray(ran).sum()))
             if int(nact) == 0 or budget_hit():
@@ -1576,6 +1642,7 @@ def run_sharded(engine, algorithm: str, backend, *,
                 iters, comp = _replay_all_nodes_chunk(
                     planner, ss, 0, 0, None, np.asarray(upds), ran,
                     upd_hist, comp_hist, iters, comp, om)
+                _count_row_bcasts(bcasts, ran, per_pass)
                 if sp.active:
                     sp.set(passes_run=int(ran.sum()))
             if bool(done_j) or budget_hit():
@@ -1596,6 +1663,7 @@ def run_sharded(engine, algorithm: str, backend, *,
                     planner, ss, 0, 0, None, front_masks(fronts),
                     np.asarray(upds), np.asarray(ran), upd_hist, comp_hist,
                     iters, comp, om, "semicore+")
+                _count_row_bcasts(bcasts, ran, per_pass)
                 if sp.active:
                     sp.set(passes_run=int(np.asarray(ran).sum()))
             if int(nact) == 0 or budget_hit():

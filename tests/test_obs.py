@@ -509,8 +509,9 @@ def test_build_structure_counts_edge_table_h2d_bytes():
     rs = resident.build_structure(eng.planner)
     d = get_registry().delta(before)
     assert rs.E_pad > rs.E  # the padding is counted: it is uploaded too
+    # the xla table is nbr and segptr: no per-slot owner table is uploaded
     assert d['repro_resident_h2d_bytes_total{what="edge_table"}'] == \
-        2 * rs.E_pad * 4 + (g.n + 1) * 4
+        rs.E_pad * 4 + (g.n + 1) * 4
     assert d.get('repro_resident_h2d_bytes_total{what="state"}', 0.0) == 0.0
 
 

@@ -144,7 +144,7 @@ def test_xla_chunk_compiles_at_lj_shape(one_chip):
     compiled = fn.lower(
         _vec(LJ_N, jnp.int32, one_chip), _vec(LJ_N, jnp.int32, one_chip),
         _vec(LJ_N, jnp.bool_, one_chip), _vec(E_pad, jnp.int32, one_chip),
-        _vec(E_pad, jnp.int32, one_chip), _vec(LJ_N + 1, jnp.int32, one_chip),
+        _vec(LJ_N + 1, jnp.int32, one_chip),
         num_probes=LJ_PROBES, num_segments=LJ_N,
         chunk=resident.chunk_len()).compile()
     assert _fits_hbm(compiled)
