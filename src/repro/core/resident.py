@@ -93,6 +93,17 @@ _ROW_BCAST = _metrics.counter(
     "Node vectors spread to edge slots by the resident chunk programs, by "
     "how: from segment offsets (segptr) or by a per-slot owner gather",
 )
+_SHARD_ALLGATHER = _metrics.counter(
+    "repro_shard_allgather_bytes_total",
+    "Bytes of the int32 arrays the sharded chunk programs' all_gathers "
+    "return: the owned ids once a chunk call, the owned core slices once a "
+    "pass that ran",
+)
+_SHARD_SLOTS = _metrics.counter(
+    "repro_shard_slots_total",
+    "Edge slots of each sharded structure built, by kind: real edges or the "
+    "padding of the rectangular (S, Emax) layout",
+)
 
 
 def _count_row_bcasts(series, ran, per_pass: int) -> None:
@@ -1125,6 +1136,8 @@ def build_sharded_structure(planner, num_shards: int,
     device list (default: the first ``num_shards`` visible devices)."""
     with _trace.span("resident.bind", cat="engine", shards=num_shards) as sp:
         ss = _build_sharded_structure(planner, num_shards, devices)
+        _SHARD_SLOTS.labels(kind="real").inc(ss.E)
+        _SHARD_SLOTS.labels(kind="pad").inc(ss.pad_edges)
         if sp.active:
             sp.set(E=ss.E, E_pad=ss.E + ss.pad_edges, bytes=sum(
                 a.nbytes for a in (ss.dst_j, ss.rows_j, ss.emask_j,
@@ -1463,7 +1476,9 @@ def run_sharded(engine, algorithm: str, backend, *,
     checkpoint demos — the partial core is a valid upper bound by monotone
     convergence.
     """
+    import jax
     import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from .engine import DecompResult
 
@@ -1483,9 +1498,10 @@ def run_sharded(engine, algorithm: str, backend, *,
         core = engine.degrees().astype(np.int64)
     cmax = int(core.max()) if n else 0
     num_probes = max(1, int(np.ceil(np.log2(cmax + 2))))
-    core_j = jnp.asarray(core.astype(np.int32))
+    core_j = jnp.asarray(_h2d(core.astype(np.int32), _H2D_STATE))
     bcasts = _ROW_BCAST.labels(how="gather")  # per-shard rows, as flat xla
     per_pass = num_probes + (2 if algorithm == "semicore*" else 0)
+    gathered = ss.S * ss.V * 4  # bytes of one all_gather's int32 result
 
     upd_hist: list = []
     comp_hist: list = []
@@ -1508,9 +1524,17 @@ def run_sharded(engine, algorithm: str, backend, *,
 
     def front_masks(fronts):
         """(chunk, S, V) pass-start owned slices -> (chunk, n) bool masks."""
-        fronts = np.asarray(fronts)
-        return np.stack([globalize(fronts[k], False, bool)
-                         for k in range(len(fronts))])
+        fronts = np.asarray(fronts)  # waits for the chunk, outside the span
+        with _trace.span("resident.globalize", cat="engine"):
+            return np.stack([globalize(fronts[k], False, bool)
+                             for k in range(len(fronts))])
+
+    def count_chunk(ran):
+        """Counters of one chunk call's passes that ran (``ran``, the
+        per-pass flags it returned): row broadcasts, and the all_gathers
+        (the owned ids once, the owned core slices once a pass)."""
+        _count_row_bcasts(bcasts, ran, per_pass)
+        _SHARD_ALLGATHER.inc((1 + int(np.asarray(ran).sum())) * gathered)
 
     def budget_hit():
         return max_supersteps is not None and iters >= max_supersteps
@@ -1587,14 +1611,19 @@ def run_sharded(engine, algorithm: str, backend, *,
         if not active0.any():
             # settled warm state: zero passes, like numpy's while-loop
             return result(core, cnt)
-        cnt_lj = localize(cnt, 0, np.int32)
-        act_lj = localize(active0, False, bool)
+        # host state the first chunk call uploads (its outputs stay on the
+        # mesh); the settle mask goes up once, sharded as the chunk takes it
+        cnt_lj = _h2d(localize(cnt, 0, np.int32), _H2D_STATE)
+        act_lj = _h2d(localize(active0, False, bool), _H2D_STATE)
         cand_args = ()
         if settle_mask is not None:
-            cand_args = (localize(
-                np.asarray(settle_mask, dtype=bool), False, bool),)
-        nact = np.int32(active0.sum())
-        while True:
+            cand_args = (jax.device_put(
+                _h2d(localize(np.asarray(settle_mask, dtype=bool), False,
+                              bool), _H2D_STATE),
+                NamedSharding(ss.mesh, P(tuple(ss.mesh.axis_names)))),)
+        nact = _h2d(np.int32(active0.sum()), _H2D_STATE)
+        cnt = None
+        while cnt is None:
             with _trace.span("resident.chunk", cat="engine",
                              algorithm="semicore*", backend=backend.name,
                              shards=ss.S, chunk=chunk) as sp:
@@ -1606,12 +1635,14 @@ def run_sharded(engine, algorithm: str, backend, *,
                     planner, ss, 0, 0, None, front_masks(fronts),
                     np.asarray(upds), np.asarray(ran), upd_hist, comp_hist,
                     iters, comp, om, "semicore*")
-                _count_row_bcasts(bcasts, ran, per_pass)
+                count_chunk(ran)
+                if int(nact) == 0 or budget_hit():
+                    cnt_lj = np.asarray(cnt_lj)
+                    with _trace.span("resident.globalize", cat="engine"):
+                        cnt = globalize(cnt_lj, 0, np.int64)
                 if sp.active:
                     sp.set(passes_run=int(np.asarray(ran).sum()))
-            if int(nact) == 0 or budget_hit():
-                break
-        return result(core_j, globalize(cnt_lj, 0, np.int64))
+        return result(core_j, cnt)
 
     # ------------------------------------------------- semicore / semicore+
     if ss.E == 0:
@@ -1642,7 +1673,7 @@ def run_sharded(engine, algorithm: str, backend, *,
                 iters, comp = _replay_all_nodes_chunk(
                     planner, ss, 0, 0, None, np.asarray(upds), ran,
                     upd_hist, comp_hist, iters, comp, om)
-                _count_row_bcasts(bcasts, ran, per_pass)
+                count_chunk(ran)
                 if sp.active:
                     sp.set(passes_run=int(ran.sum()))
             if bool(done_j) or budget_hit():
@@ -1650,8 +1681,9 @@ def run_sharded(engine, algorithm: str, backend, *,
         return result(core_j, None)
 
     if algorithm == "semicore+":
-        act_lj = localize(np.ones(n, dtype=bool), False, bool)
-        nact = np.int32(n)
+        act_lj = _h2d(localize(np.ones(n, dtype=bool), False, bool),
+                      _H2D_STATE)
+        nact = _h2d(np.int32(n), _H2D_STATE)
         while True:
             with _trace.span("resident.chunk", cat="engine",
                              algorithm="semicore+", backend=backend.name,
@@ -1663,7 +1695,7 @@ def run_sharded(engine, algorithm: str, backend, *,
                     planner, ss, 0, 0, None, front_masks(fronts),
                     np.asarray(upds), np.asarray(ran), upd_hist, comp_hist,
                     iters, comp, om, "semicore+")
-                _count_row_bcasts(bcasts, ran, per_pass)
+                count_chunk(ran)
                 if sp.active:
                     sp.set(passes_run=int(np.asarray(ran).sum()))
             if int(nact) == 0 or budget_hit():

@@ -303,3 +303,137 @@ def test_distributed_decompose_budgeted_prefix_and_warm_restart():
     core2, extra = distributed_decompose(g, core0=partial)
     np.testing.assert_array_equal(core2, expect)
     assert extra <= iters
+
+
+# ------------------------------------- four devices: counters and spans
+_FOUR_DEVICE_SCRIPT = r"""
+import json
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+import jax
+assert len(jax.devices()) == 4
+from repro.core.distributed import shard_arrays
+from repro.core.imcore import imcore_bz
+from repro.core.semicore import decompose
+from repro.graph import chung_lu
+from repro.obs import trace
+from repro.obs.metrics import get_registry
+
+# LJ's density (m/n = 14.2) and power law (gamma 2.5) at 3,000 nodes
+g = chung_lu(3000, 42700, gamma=2.5, seed=20151102)
+ref = decompose(g, "semicore*", "batch", block_edges=256, backend="numpy")
+decompose(g, "semicore*", "batch", block_edges=256, backend="shard")
+before = get_registry().snapshot()
+trace.clear_trace()
+trace.start_trace()
+r = decompose(g, "semicore*", "batch", block_edges=256, backend="shard")
+trace.stop_trace()
+spans = [(e["name"], e["ts"], e["ts"] + e["dur"])
+         for e in trace.get_collector().events if e["ph"] == "X"]
+sg = shard_arrays(np.asarray(g.adj), g.indptr, 4, n=g.n)
+
+
+def walk(x):
+    return [x.iterations, x.node_computations, x.edge_block_reads,
+            x.node_table_reads, list(x.updates_per_iter),
+            list(x.computations_per_iter)]
+
+
+print(json.dumps({
+    "n": g.n, "E": int(g.num_directed), "S": int(sg.owned_ids.shape[0]),
+    "V": int(sg.owned_ids.shape[1]), "pad": int(sg.pad_edges),
+    "num_shards": r.num_shards, "shard_pad_edges": r.shard_pad_edges,
+    "core_matches_numpy": bool(np.array_equal(r.core, ref.core)),
+    "core_matches_peel": bool(np.array_equal(r.core, imcore_bz(g))),
+    "cnt_matches_numpy": bool(np.array_equal(r.cnt, ref.cnt)),
+    "walk": walk(r), "walk_numpy": walk(ref),
+    "delta": get_registry().delta(before), "spans": spans,
+}))
+"""
+
+
+@pytest.fixture(scope="module")
+def four_devices():
+    """One decompose of an LJ-shaped graph on a forced 4-device host: its
+    result, its registry deltas and its spans."""
+    import json
+
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c", _FOUR_DEVICE_SCRIPT],
+        capture_output=True, text=True, env=env, timeout=600,
+        cwd=os.path.dirname(os.path.dirname(__file__)),
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _chunk_spans(run):
+    return [s for s in run["spans"] if s[0] == "resident.chunk"]
+
+
+def test_four_shard_lj_shaped_decompose_matches_numpy(four_devices):
+    run = four_devices
+    assert run["num_shards"] == run["S"] == 4
+    assert run["core_matches_numpy"] and run["core_matches_peel"]
+    assert run["cnt_matches_numpy"]
+    assert run["walk"] == run["walk_numpy"]  # passes and the I/O trace
+    assert run["walk"][0] > 8  # more than one chunk call
+
+
+def test_four_shard_allgather_bytes_are_ids_per_call_and_core_per_pass(
+        four_devices):
+    run = four_devices
+    calls, passes = len(_chunk_spans(run)), run["walk"][0]
+    assert calls == -(-passes // resident.chunk_len())
+    assert run["delta"]["repro_shard_allgather_bytes_total"] == \
+        (calls + passes) * run["S"] * run["V"] * 4
+
+
+def test_four_shard_slots_count_the_layout(four_devices):
+    run = four_devices
+    d = run["delta"]
+    assert d['repro_shard_slots_total{kind="real"}'] == run["E"]
+    assert d['repro_shard_slots_total{kind="pad"}'] == run["pad"] == \
+        run["shard_pad_edges"]
+
+
+def test_four_shard_state_h2d_bytes_are_the_arrays_nbytes(four_devices):
+    run = four_devices
+    S, V = run["S"], run["V"]
+    # core (n int32), cnt (S, V) int32, the frontier (S, V) bool, nact
+    assert run["delta"]['repro_resident_h2d_bytes_total{what="state"}'] == \
+        run["n"] * 4 + S * V * 4 + S * V + 4
+
+
+def test_four_shard_globalize_spans_nest_in_chunk_spans(four_devices):
+    run = four_devices
+    chunks = _chunk_spans(run)
+    glob = [s for s in run["spans"] if s[0] == "resident.globalize"]
+    # each call's frontier masks, and the last call's cnt
+    assert len(glob) == len(chunks) + 1
+    for _, a, b in glob:
+        assert any(c0 <= a and b <= c1 for _, c0, c1 in chunks)
+
+
+def test_shard_settle_mask_uploads_once():
+    """A masked settle over many chunk calls uploads its state once: the
+    settle mask goes up sharded before the first call, not with each."""
+    from repro.obs.metrics import get_registry
+
+    g = chung_lu(400, 1600, seed=8)
+    eng = HostEngine(g, block_edges=64)
+    core = g.degrees().astype(np.int64)
+    mask = np.ones(g.n, dtype=bool)
+    mask[::3] = False
+    before = get_registry().snapshot()
+    r = resident.run_resident(eng, "semicore*", ShardedBackend(num_shards=1),
+                              core=core, cnt=np.zeros(g.n, np.int64),
+                              superstep_chunk=1, settle_mask=mask)
+    d = get_registry().delta(before)
+    assert r.iterations > 3  # as many chunk calls
+    V = shard_graph(g, 1).owned_ids.shape[1]
+    # core, cnt, frontier, settle mask, nact
+    assert d['repro_resident_h2d_bytes_total{what="state"}'] == \
+        g.n * 4 + V * 4 + V + V + 4
