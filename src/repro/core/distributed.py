@@ -52,14 +52,15 @@ class ShardedGraph:
 
     ``lsegptr`` holds each shard's *local* CSR offsets over its padded edge
     axis (empty segments for padding slots), so the on-mesh superstep can run
-    its segment reductions as sorted prefix sums instead of scatters.
+    its segment reductions as sorted prefix sums instead of scatters, and
+    spread owned values to edge slots from the same offsets: no per-slot
+    owner table is built.
     ``pad_edges`` / ``per_shard_edges`` surface the padding cost of the
     rectangular (S, E) layout; the minimax balance below keeps it minimal
     for contiguous ranges.
     """
 
     dst: np.ndarray        # (S, E) int32  — edge targets, padded
-    rows: np.ndarray       # (S, E) int32  — local owner-row per edge
     edge_mask: np.ndarray  # (S, E) bool
     owned_ids: np.ndarray  # (S, V) int32  — global node id per local slot (pad -> n)
     owned_mask: np.ndarray # (S, V) bool
@@ -71,19 +72,12 @@ class ShardedGraph:
     pad_edges: int         # S * E - total directed edges (wasted slots)
     per_shard_edges: np.ndarray  # (S,) int64 — real edges per shard
 
-    def device_arrays(self) -> dict:
-        return dict(
-            dst=self.dst, rows=self.rows, edge_mask=self.edge_mask,
-            owned_ids=self.owned_ids, owned_mask=self.owned_mask,
-            lsegptr=self.lsegptr,
-        )
-
 
 def _validate_int32(total_edges: int, n: int) -> None:
-    """The device shard tables are int32 end-to-end (ids, rows, local
-    offsets; jax x64 is off) — fail loudly instead of wrapping offsets
-    negative and converging to a silently-wrong core array (the same
-    guard ``resident.build_structure`` applies to the flat table)."""
+    """The device shard tables are int32 end-to-end (ids, local offsets;
+    jax x64 is off) — fail loudly instead of wrapping offsets negative and
+    converging to a silently-wrong core array (the same guard
+    ``resident.build_structure`` applies to the flat table)."""
     if total_edges >= (1 << 31) or n >= (1 << 31):
         raise ValueError(
             f"sharded edge table needs int32 offsets: 2m={total_edges} "
@@ -146,7 +140,6 @@ def shard_arrays(adj: np.ndarray, seg_ptr: np.ndarray, num_shards: int,
     max_nodes = int(max(1, np.diff(bounds).max() if n else 1))
     max_edges = int(max(1, per_shard.max()))
     dst = np.zeros((S, max_edges), dtype=np.int32)
-    rows = np.zeros((S, max_edges), dtype=np.int32)
     emask = np.zeros((S, max_edges), dtype=bool)
     owned = np.full((S, max_nodes), n, dtype=np.int32)
     omask = np.zeros((S, max_nodes), dtype=bool)
@@ -156,8 +149,6 @@ def shard_arrays(adj: np.ndarray, seg_ptr: np.ndarray, num_shards: int,
         e0, e1 = int(seg_ptr[lo_v]), int(seg_ptr[hi_v])
         ne, nv = e1 - e0, hi_v - lo_v
         dst[s, :ne] = adj[e0:e1]
-        local_deg = np.diff(seg_ptr[lo_v: hi_v + 1]).astype(np.int64)
-        rows[s, :ne] = np.repeat(np.arange(nv, dtype=np.int32), local_deg)
         emask[s, :ne] = True
         owned[s, :nv] = np.arange(lo_v, hi_v, dtype=np.int32)
         omask[s, :nv] = True
@@ -166,7 +157,7 @@ def shard_arrays(adj: np.ndarray, seg_ptr: np.ndarray, num_shards: int,
     deg = np.diff(seg_ptr).astype(np.int32)
     dmax = int(deg.max()) if n else 0
     return ShardedGraph(
-        dst=dst, rows=rows, edge_mask=emask, owned_ids=owned,
+        dst=dst, edge_mask=emask, owned_ids=owned,
         owned_mask=omask, lsegptr=lseg, bounds=bounds, deg=deg, n=n,
         num_probes=max(1, int(np.ceil(np.log2(dmax + 2)))),
         pad_edges=S * max_edges - total, per_shard_edges=per_shard,
@@ -192,7 +183,6 @@ def sharded_graph_specs(
     sds = jax.ShapeDtypeStruct
     specs = dict(
         dst=sds((S, E), jnp.int32),
-        rows=sds((S, E), jnp.int32),
         edge_mask=sds((S, E), jnp.bool_),
         lsegptr=sds((S, V + 1), jnp.int32),
         owned_ids=sds((S, V), jnp.int32),

@@ -1099,7 +1099,8 @@ class ShardedStructure:
     and device_put once per structural version — the same version-keyed
     cache contract as :class:`ResidentStructure`.  Host copies of the
     owned-slot maps stay for reassembling global masks/arrays from the
-    per-shard slices the chunk fns emit.
+    per-shard slices the chunk fns emit.  No per-slot owner table goes up:
+    the chunk programs spread owned values to edge slots from ``lseg``.
     """
 
     graph: object            # base CSRGraph this structure was built from
@@ -1116,7 +1117,6 @@ class ShardedStructure:
     per_shard_edges: np.ndarray  # (S,) int64
     mesh: object             # jax Mesh over the first S devices
     dst_j: object            # (S, Emax) int32, sharded
-    rows_j: object           # (S, Emax) int32, sharded
     emask_j: object          # (S, Emax) bool, sharded
     lseg_j: object           # (S, V+1) int32, sharded — local CSR offsets
     owned_ids_j: object      # (S, V) int32, sharded
@@ -1140,9 +1140,8 @@ def build_sharded_structure(planner, num_shards: int,
         _SHARD_SLOTS.labels(kind="pad").inc(ss.pad_edges)
         if sp.active:
             sp.set(E=ss.E, E_pad=ss.E + ss.pad_edges, bytes=sum(
-                a.nbytes for a in (ss.dst_j, ss.rows_j, ss.emask_j,
-                                   ss.lseg_j, ss.owned_ids_j,
-                                   ss.owned_mask_j)))
+                a.nbytes for a in (ss.dst_j, ss.emask_j, ss.lseg_j,
+                                   ss.owned_ids_j, ss.owned_mask_j)))
     return ss
 
 
@@ -1182,7 +1181,6 @@ def _build_sharded_structure(planner, num_shards: int,
         per_shard_edges=sg.per_shard_edges,
         mesh=mesh,
         dst_j=put(sg.dst),
-        rows_j=put(sg.rows),
         emask_j=put(sg.edge_mask),
         lseg_j=put(sg.lsegptr),
         owned_ids_j=put(sg.owned_ids),
@@ -1191,18 +1189,12 @@ def _build_sharded_structure(planner, num_shards: int,
 
 
 def _local_segsum(lseg):
-    """Per-shard segment sum over the shard's *sorted* local rows: prefix
-    sums + boundary gathers (the :func:`_sorted_segsum` discipline applied
-    to the shard's local offsets; padding slots are empty trailing
+    """Per-shard segment sum over the shard's *sorted* local rows:
+    :func:`_sorted_segsum` over the shard's local offsets, in the probe
+    ops' (vals, rows, num_segments) form (padding slots are empty trailing
     segments, so padded edges never contribute)."""
-    import jax.numpy as jnp
-
-    def segsum(vals, _rows, _num_segments):
-        cs = jnp.concatenate([jnp.zeros((1,), vals.dtype), jnp.cumsum(vals)])
-        return (jnp.take(cs, lseg[1:], mode="clip")
-                - jnp.take(cs, lseg[:-1], mode="clip"))
-
-    return segsum
+    apply_ = _sorted_segsum(lseg)
+    return lambda vals, _rows, _num_segments: apply_(vals)
 
 
 @lru_cache(maxsize=None)
@@ -1220,6 +1212,14 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
     inactive neighbor ``core2 == core`` makes the push predicate
     unsatisfiable, so no activity mask crosses shards), which keeps every
     superstep at exactly one all_gather.
+
+    Owned-slot values reach the shard's edge slots by :func:`_row_bcast`
+    over the local offsets ``lseg``, as the flat xla path's do over
+    ``segptr``, so the programs take no per-slot owner table.  ``lseg`` is
+    sorted, not unique: owned pad slots are empty trailing segments at the
+    local E, and where the local E is ``Emax`` the broadcast's scatter drops
+    them.  Slots at or past the local E get whatever the broadcast leaves;
+    ``emask`` and the ``lseg``-bounded segment sums keep them out.
 
     Per-pass owned frontier slices come back through the scan's ys —
     sharded outputs, no extra collective — for the host accounting replay.
@@ -1251,11 +1251,12 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
 
     if algorithm == "semicore":
         # every node, every pass; done after the first no-update pass
-        def body(core, done, dst, rows, emask, lseg, owned_ids, owned_mask):
+        def body(core, done, dst, emask, lseg, owned_ids, owned_mask):
             _count_trace(name)
-            dst, rows, emask, lseg, owned_ids, owned_mask = strip(
-                dst, rows, emask, lseg, owned_ids, owned_mask)
+            dst, emask, lseg, owned_ids, owned_mask = strip(
+                dst, emask, lseg, owned_ids, owned_mask)
             segsum = _local_segsum(lseg)
+            bcast = _row_bcast(lseg, dst.shape[0])
             owned_flat = flat_ids(owned_ids)
 
             def run(args):
@@ -1263,8 +1264,9 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
                 nbr_vals = jnp.take(core, dst, mode="clip")
                 c_old = jnp.where(owned_mask,
                                   jnp.take(core, owned_ids, mode="clip"), 0)
-                h = hindex_bsearch(nbr_vals, rows, emask, c_old, num_probes,
-                                   segment_sum_fn=segsum, unroll=unroll)
+                h = hindex_bsearch(nbr_vals, None, emask, c_old, num_probes,
+                                   segment_sum_fn=segsum, unroll=unroll,
+                                   row_bcast_fn=bcast)
                 core2 = gather_core(core, h, owned_flat)
                 upd = jnp.sum((core2 != core).astype(jnp.int32))
                 return (core2, upd == 0), upd
@@ -1281,19 +1283,20 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
                 step, (core, done), None, length=chunk)
             return core, done, upds, ran
 
-        in_specs = (repl, repl, shard, shard, shard, shard, shard, shard)
+        in_specs = (repl, repl, shard, shard, shard, shard, shard)
         out_specs = (repl, repl, repl, repl)
 
     elif algorithm == "semicore+":
         # neighbors of changed nodes (Lemma 4.1), alive nodes only; the
         # changed mask is derived globally from the gathered core
         # (core2 != core), so propagation is a local row reduction
-        def body(core, active_b, nact, dst, rows, emask, lseg, owned_ids,
+        def body(core, active_b, nact, dst, emask, lseg, owned_ids,
                  owned_mask):
             _count_trace(name)
-            dst, rows, emask, lseg, owned_ids, owned_mask, active0 = strip(
-                dst, rows, emask, lseg, owned_ids, owned_mask, active_b)
+            dst, emask, lseg, owned_ids, owned_mask, active0 = strip(
+                dst, emask, lseg, owned_ids, owned_mask, active_b)
             segsum = _local_segsum(lseg)
+            bcast = _row_bcast(lseg, dst.shape[0])
             owned_flat = flat_ids(owned_ids)
 
             def run(args):
@@ -1302,13 +1305,14 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
                 c_owned = jnp.where(owned_mask,
                                     jnp.take(core, owned_ids, mode="clip"), 0)
                 c_old = jnp.where(active, c_owned, 0)
-                h = hindex_bsearch(nbr_vals, rows, emask, c_old, num_probes,
-                                   segment_sum_fn=segsum, unroll=unroll)
+                h = hindex_bsearch(nbr_vals, None, emask, c_old, num_probes,
+                                   segment_sum_fn=segsum, unroll=unroll,
+                                   row_bcast_fn=bcast)
                 c_new = jnp.where(active, h, c_owned)
                 core2 = gather_core(core, c_new, owned_flat)
                 upd = jnp.sum((core2 != core).astype(jnp.int32))
                 changed_e = jnp.take(core2 != core, dst, mode="clip") & emask
-                touched = segsum(changed_e.astype(jnp.int32), rows, 0)
+                touched = segsum(changed_e.astype(jnp.int32), None, 0)
                 active2 = (touched > 0) & (c_new > 0) & owned_mask
                 nact2 = jax.lax.psum(
                     jnp.sum(active2.astype(jnp.int32)), axes)
@@ -1327,8 +1331,7 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
                 step, (core, active0, nact), None, length=chunk)
             return (core, active[None], nact, fronts[:, None, :], upds, ran)
 
-        in_specs = (repl, shard, repl, shard, shard, shard, shard, shard,
-                    shard)
+        in_specs = (repl, shard, repl, shard, shard, shard, shard, shard)
         out_specs = (repl, shard, repl, P(None, axes, None), repl, repl)
 
     elif algorithm == "semicore*":
@@ -1340,15 +1343,15 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
         def body(core, cnt_b, active_b, nact, *tail):
             _count_trace(name)
             if masked:
-                cand_b, dst, rows, emask, lseg, owned_ids, owned_mask = tail
+                cand_b, dst, emask, lseg, owned_ids, owned_mask = tail
                 (cand,) = strip(cand_b)
             else:
-                dst, rows, emask, lseg, owned_ids, owned_mask = tail
+                dst, emask, lseg, owned_ids, owned_mask = tail
                 cand = None
-            dst, rows, emask, lseg, owned_ids, owned_mask, cnt0, active0 = \
-                strip(dst, rows, emask, lseg, owned_ids, owned_mask, cnt_b,
-                      active_b)
+            dst, emask, lseg, owned_ids, owned_mask, cnt0, active0 = strip(
+                dst, emask, lseg, owned_ids, owned_mask, cnt_b, active_b)
             segsum = _local_segsum(lseg)
+            bcast = _row_bcast(lseg, dst.shape[0])
             owned_flat = flat_ids(owned_ids)
 
             def run(args):
@@ -1357,24 +1360,26 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
                 c_owned = jnp.where(owned_mask,
                                     jnp.take(core, owned_ids, mode="clip"), 0)
                 c_old = jnp.where(active, c_owned, 0)
-                h = hindex_bsearch(nbr_vals, rows, emask, c_old, num_probes,
-                                   segment_sum_fn=segsum, unroll=unroll)
+                h = hindex_bsearch(nbr_vals, None, emask, c_old, num_probes,
+                                   segment_sum_fn=segsum, unroll=unroll,
+                                   row_bcast_fn=bcast)
                 c_new = jnp.where(active, h, c_owned)
                 core2 = gather_core(core, c_new, owned_flat)
                 upd = jnp.sum((core2 != core).astype(jnp.int32))
                 # (1) recompute cnt of the frontier vs pass-start values
                 thr = jnp.where(active, h, 0)
-                refreshed = edge_ge_counts(nbr_vals, rows, emask, thr,
+                refreshed = edge_ge_counts(nbr_vals, None, emask, thr,
                                            c_old.shape[0],
-                                           segment_sum_fn=segsum)
+                                           segment_sum_fn=segsum,
+                                           row_bcast_fn=bcast)
                 # (2) push decrements: dec[u] = #{edges (v in F -> u) :
                 #     core_now(u) in (h(v), c_old(v)]} — core2[v] stands in
                 #     for h(v) (equal where v is active; for inactive v,
                 #     core2 == core makes the interval empty)
-                c2_row = jnp.take(c_new, rows, mode="clip")
+                c2_row = bcast(c_new)
                 push = (emask & (c2_row > jnp.take(core2, dst, mode="clip"))
                         & (c2_row <= nbr_vals))
-                dec = segsum(push.astype(jnp.int32), rows, 0)
+                dec = segsum(push.astype(jnp.int32), None, 0)
                 cnt2 = jnp.where(active, refreshed, cnt) - dec
                 active2 = (cnt2 < c_new) & (c_new > 0) & owned_mask
                 if cand is not None:
@@ -1399,7 +1404,7 @@ def _shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
 
         in_specs = (repl, shard, shard, repl) \
             + ((shard,) if masked else ()) \
-            + (shard, shard, shard, shard, shard, shard)
+            + (shard, shard, shard, shard, shard)
         out_specs = (repl, shard, shard, repl, P(None, axes, None), repl,
                      repl)
 
@@ -1426,7 +1431,8 @@ def build_shard_chunk_fn(mesh, algorithm: str, n: int, num_probes: int,
 @lru_cache(maxsize=None)
 def _shard_counts_fn(mesh, n: int):
     """Full-table exact-cnt scan (warm_settle's Eq. 2 prologue), on-mesh:
-    each shard counts its owned nodes' thresholds over local edges."""
+    each shard counts its owned nodes' thresholds over local edges, the
+    thresholds spread to edge slots from ``lseg`` (:func:`_row_bcast`)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1438,19 +1444,20 @@ def _shard_counts_fn(mesh, n: int):
     repl = P()
     name = _program_name("counts_all", None, "shard")
 
-    def body(core, dst, rows, emask, lseg, owned_ids, owned_mask):
+    def body(core, dst, emask, lseg, owned_ids, owned_mask):
         _count_trace(name)
-        dst = dst[0]; rows = rows[0]; emask = emask[0]; lseg = lseg[0]
+        dst = dst[0]; emask = emask[0]; lseg = lseg[0]
         owned_ids = owned_ids[0]; owned_mask = owned_mask[0]
-        segsum = _local_segsum(lseg)
         c_owned = jnp.where(owned_mask,
                             jnp.take(core, owned_ids, mode="clip"), 0)
         nbr_vals = jnp.take(core, dst, mode="clip")
-        cnt = edge_ge_counts(nbr_vals, rows, emask, c_owned,
-                             c_owned.shape[0], segment_sum_fn=segsum)
+        cnt = edge_ge_counts(nbr_vals, None, emask, c_owned,
+                             c_owned.shape[0],
+                             segment_sum_fn=_local_segsum(lseg),
+                             row_bcast_fn=_row_bcast(lseg, dst.shape[0]))
         return cnt[None]
 
-    in_specs = (repl, shard, shard, shard, shard, shard, shard)
+    in_specs = (repl, shard, shard, shard, shard, shard)
     sharded = jax.shard_map(_named(body, name), mesh=mesh, in_specs=in_specs,
                             out_specs=shard, check_vma=False)
     return jax.jit(
@@ -1499,7 +1506,7 @@ def run_sharded(engine, algorithm: str, backend, *,
     cmax = int(core.max()) if n else 0
     num_probes = max(1, int(np.ceil(np.log2(cmax + 2))))
     core_j = jnp.asarray(_h2d(core.astype(np.int32), _H2D_STATE))
-    bcasts = _ROW_BCAST.labels(how="gather")  # per-shard rows, as flat xla
+    bcasts = _ROW_BCAST.labels(how="segptr")  # from each shard's lseg
     per_pass = num_probes + (2 if algorithm == "semicore*" else 0)
     gathered = ss.S * ss.V * 4  # bytes of one all_gather's int32 result
 
@@ -1579,8 +1586,8 @@ def run_sharded(engine, algorithm: str, backend, *,
                 planner.account_node_scan(0, n - 1)
                 if ss.E:
                     counts = _shard_counts_fn(ss.mesh, n)
-                    cnt_lj = counts(core_j, ss.dst_j, ss.rows_j, ss.emask_j,
-                                    ss.lseg_j, ss.owned_ids_j, ss.owned_mask_j)
+                    cnt_lj = counts(core_j, ss.dst_j, ss.emask_j, ss.lseg_j,
+                                    ss.owned_ids_j, ss.owned_mask_j)
                     bcasts.inc()
                     cnt = globalize(cnt_lj, 0, np.int64)
                 else:
@@ -1629,8 +1636,7 @@ def run_sharded(engine, algorithm: str, backend, *,
                              shards=ss.S, chunk=chunk) as sp:
                 core_j, cnt_lj, act_lj, nact, fronts, upds, ran = budget_fn()(
                     core_j, cnt_lj, act_lj, nact, *cand_args, ss.dst_j,
-                    ss.rows_j, ss.emask_j, ss.lseg_j, ss.owned_ids_j,
-                    ss.owned_mask_j)
+                    ss.emask_j, ss.lseg_j, ss.owned_ids_j, ss.owned_mask_j)
                 iters, comp = _replay_chunk(
                     planner, ss, 0, 0, None, front_masks(fronts),
                     np.asarray(upds), np.asarray(ran), upd_hist, comp_hist,
@@ -1667,8 +1673,8 @@ def run_sharded(engine, algorithm: str, backend, *,
                              algorithm="semicore", backend=backend.name,
                              shards=ss.S, chunk=chunk) as sp:
                 core_j, done_j, upds, ran = budget_fn()(
-                    core_j, done_j, ss.dst_j, ss.rows_j, ss.emask_j,
-                    ss.lseg_j, ss.owned_ids_j, ss.owned_mask_j)
+                    core_j, done_j, ss.dst_j, ss.emask_j, ss.lseg_j,
+                    ss.owned_ids_j, ss.owned_mask_j)
                 ran = np.asarray(ran)
                 iters, comp = _replay_all_nodes_chunk(
                     planner, ss, 0, 0, None, np.asarray(upds), ran,
@@ -1689,8 +1695,8 @@ def run_sharded(engine, algorithm: str, backend, *,
                              algorithm="semicore+", backend=backend.name,
                              shards=ss.S, chunk=chunk) as sp:
                 core_j, act_lj, nact, fronts, upds, ran = budget_fn()(
-                    core_j, act_lj, nact, ss.dst_j, ss.rows_j, ss.emask_j,
-                    ss.lseg_j, ss.owned_ids_j, ss.owned_mask_j)
+                    core_j, act_lj, nact, ss.dst_j, ss.emask_j, ss.lseg_j,
+                    ss.owned_ids_j, ss.owned_mask_j)
                 iters, comp = _replay_chunk(
                     planner, ss, 0, 0, None, front_masks(fronts),
                     np.asarray(upds), np.asarray(ran), upd_hist, comp_hist,

@@ -158,10 +158,10 @@ def _memory_model(arch, shape, mesh, bundle, chips) -> dict:
     grads = 0.0
     if cfg.kind == "coregraph":
         # replicated node state (core in + combined out) + per-chip edge
-        # shard (dst/rows/mask) + per-chip owned-slot state (ids/mask/
+        # shard (dst/mask) + per-chip owned-slot state (ids/mask/
         # lsegptr/cnt/active); the scatter id map is gathered on-mesh per
         # chunk, not shipped replicated (resident._shard_chunk_fn)
-        args = 2 * cfg.n * 4 + cfg.m_directed / chips * 9 \
+        args = 2 * cfg.n * 4 + cfg.m_directed / chips * 5 \
             + cfg.n / chips * 14
         act = cfg.m_directed / chips * 8  # gathered nbr cores + index arrays
     elif bundle.name == "train_step" and cfg.kind == "lm":

@@ -313,8 +313,8 @@ def _build_coregraph(cfg: CoreGraphConfig, shape_name, step_kind, avals, mesh,
     num_probes = avals["num_probes"]
     fn = build_shard_chunk_fn(mesh, "semicore*", cfg.n, num_probes, chunk=1)
     args = (specs["core0"], specs["cnt"], specs["active"], specs["nactive"],
-            specs["dst"], specs["rows"], specs["edge_mask"],
-            specs["lsegptr"], specs["owned_ids"], specs["owned_mask"])
+            specs["dst"], specs["edge_mask"], specs["lsegptr"],
+            specs["owned_ids"], specs["owned_mask"])
     return StepBundle(
         name="decompose", fn=fn, args=args,
         in_shardings=None,  # already a jit-wrapped fn with shardings

@@ -14,6 +14,18 @@ reaches the device for them:
   isolated nodes at both ends and an edge count off the bucket grid; pallas
   builds ``rows`` only when it asks for it;
 * ``repro_resident_row_bcast_total{how}`` counts the broadcasts issued.
+
+The sharded chunk programs spread each shard's owned values to its edge
+slots the same way, from the shard's local offsets ``lseg``:
+
+* the broadcast equals ``np.repeat(x, diff(lseg))`` on every shard's real
+  slots of ``distributed.shard_arrays`` layouts: a shard whose local E is
+  ``Emax``, owned pad slots, empty segments first and in the middle, a
+  shard with no edges;
+* the sharded chunk and ``counts_all`` programs take no ``rows`` and gather
+  nothing E-sized but by ``dst``, and nothing E-sized in the probe loop;
+* decompose and both warm settles on ``backend="shard"`` stay exact and
+  count ``how="segptr"`` broadcasts only.
 """
 import jax
 import jax.numpy as jnp
@@ -22,6 +34,7 @@ import pytest
 from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.core import resident
+from repro.core.distributed import shard_arrays
 from repro.core.engine import XLABackend, run_batch, warm_settle
 from repro.core.imcore import imcore_bz
 from repro.core.maintenance import CoreMaintainer
@@ -327,3 +340,208 @@ def test_run_batch_xla_reuses_the_structure_without_rows():
     r = run_batch(eng, "semicore*", be)
     assert be._resident.pallas_table is None
     np.testing.assert_array_equal(r.core, imcore_bz(eng.graph))
+
+
+# ------------------------------------------------ the sharded programs
+def _shard_layout(case: str):
+    """``distributed.shard_arrays`` of one layout (the targets are zeros:
+    the broadcast reads only the offsets)."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    if case == "full_with_owned_pad":  # local E == Emax in every shard
+        lens, S = np.array([1] * 8 + [8, 4, 4]), 3
+    elif case == "empty_first_mid":
+        lens, S = np.array([0, 0, 3, 2, 0, 0, 4, 1, 2, 0, 3, 3, 0, 5]), 2
+    elif case == "edgeless_shards":  # more shards than nodes
+        lens, S = np.array([3, 2]), 4
+    elif case == "edgeless":  # one shard owns every node, no edge at all
+        lens, S = np.zeros(5, np.int64), 3
+    elif case.startswith("random"):
+        lens = rng.integers(0, 30, size=int(rng.integers(5, 300)))
+        lens[rng.random(len(lens)) < 0.3] = 0
+        S = int(rng.integers(2, 5))
+    else:
+        raise ValueError(case)
+    seg = np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
+    return shard_arrays(np.zeros(int(seg[-1]), np.int32), seg, S,
+                        n=len(lens))
+
+
+def _shows(sg, case: str) -> bool:
+    """Whether the layout holds the shard shape ``case`` is there for."""
+    Emax = sg.dst.shape[1]
+    lens = np.diff(sg.lsegptr, axis=1)
+    full = sg.per_shard_edges == Emax
+    owned_pad = ~sg.owned_mask.all(axis=1)
+    if case == "full_with_owned_pad":
+        return bool((full & owned_pad).any())
+    if case == "empty_first_mid":
+        return bool(((lens[:, 0] == 0) & (lens[:, 4] == 0)
+                     & sg.owned_mask[:, 4]).any())
+    if case == "edgeless_shards":
+        return bool(((sg.per_shard_edges == 0) & owned_pad).any())
+    if case == "edgeless":
+        return bool(((sg.per_shard_edges == 0) & sg.owned_mask.any(axis=1)
+                     ).any())
+    return True
+
+
+SHARD_CASES = ["full_with_owned_pad", "empty_first_mid", "edgeless_shards",
+               "edgeless"] + [f"random{i}" for i in range(4)]
+
+
+@pytest.mark.parametrize("case", SHARD_CASES)
+def test_shard_row_broadcast_equals_repeat(case):
+    sg = _shard_layout(case)
+    assert _shows(sg, case)
+    S, V = sg.owned_ids.shape
+    Emax = sg.dst.shape[1]
+    rng = np.random.default_rng(S * V)
+    for s in range(S):
+        lseg, E = sg.lsegptr[s], int(sg.per_shard_edges[s])
+        assert lseg[-1] == E <= Emax
+        x = rng.integers(-2 ** 31, 2 ** 31, size=V).astype(np.int32)
+        got = np.asarray(jax.jit(resident._row_bcast(jnp.asarray(lseg), Emax))(
+            jnp.asarray(x)))
+        assert got.shape == (Emax,)
+        np.testing.assert_array_equal(got[:E], np.repeat(x, np.diff(lseg)))
+
+
+def _one_device_mesh():
+    return jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("shard",))
+
+
+def _shard_program(variant: str, n, V, Emax, probes, chunk):
+    """One sharded program on a one-device mesh and the shapes of its
+    operands: the state, then the table ``ShardedStructure`` holds."""
+    S = 1
+    i32, b = jnp.int32, jnp.bool_
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    table = (sds((S, Emax), i32), sds((S, Emax), b), sds((S, V + 1), i32),
+             sds((S, V), i32), sds((S, V), b))
+    core = sds((n,), i32)
+    mesh = _one_device_mesh()
+    if variant == "counts_all":
+        return resident._shard_counts_fn(mesh, n), (core,) + table
+    algorithm = variant.split("-")[0]
+    masked = variant.endswith("masked")
+    fn = resident._shard_chunk_fn(mesh, algorithm, n, probes, chunk, False,
+                                  masked)
+    state = {"semicore": (core, sds((), b)),
+             "semicore+": (core, sds((S, V), b), sds((), i32)),
+             "semicore*": (core, sds((S, V), i32), sds((S, V), b),
+                           sds((), i32)) + ((sds((S, V), b),) if masked
+                                            else ())}[algorithm]
+    return fn, state + table
+
+
+# E-sized gathers each sharded program keeps: all of them by dst (the
+# pass-start core; semicore+'s changed mask; semicore*'s post-pass core)
+DST_GATHERS = {"semicore": 1, "semicore+": 2, "semicore*": 2,
+               "semicore*-masked": 2, "counts_all": 1}
+
+
+@pytest.mark.parametrize("variant", sorted(DST_GATHERS))
+def test_shard_programs_take_no_rows_and_gather_only_by_dst(variant):
+    n, V, Emax, probes, chunk = 37, 40, 256, 5, 3
+    fn, args = _shard_program(variant, n, V, Emax, probes, chunk)
+    closed = fn.trace(*args).jaxpr
+    operands = [v.aval for v in closed.jaxpr.invars]
+    assert len(operands) == len(args)
+    # one E-sized int32 operand: dst
+    assert sum(a.shape == (1, Emax) and a.dtype == jnp.int32
+               for a in operands) == 1
+    found = _walk(closed.jaxpr, False, probes, [])
+    edge_sized = [inside for shape, inside in found if shape == (Emax,)]
+    if variant != "counts_all":
+        assert any(inside for _, inside in found)  # the probe loop was found
+    assert not any(edge_sized), "an E-sized gather inside the probe loop"
+    assert len(edge_sized) == DST_GATHERS[variant]
+
+
+def test_shard_structure_uploads_no_rows():
+    import dataclasses
+
+    assert "rows_j" not in {
+        f.name for f in dataclasses.fields(resident.ShardedStructure)}
+    eng = HostEngine(_ends_isolated(), block_edges=64)
+    before = get_registry().snapshot()
+    ss = resident.build_sharded_structure(eng.planner, 1,
+                                          devices=jax.devices()[:1])
+    d = get_registry().delta(before)
+    held = (ss.dst_j, ss.emask_j, ss.lseg_j, ss.owned_ids_j, ss.owned_mask_j)
+    assert d['repro_resident_h2d_bytes_total{what="edge_table"}'] == \
+        sum(a.nbytes for a in held)
+    assert ss.dst_j.shape == (1, ss.E)  # one shard: no pad slot
+
+
+@pytest.mark.parametrize("algorithm", ["semicore", "semicore+", "semicore*"])
+def test_shard_decompose_exact_and_counts_segptr_broadcasts(algorithm):
+    g = _ends_isolated()
+    ref = decompose(g, algorithm, "batch", block_edges=64, backend="numpy")
+    before = get_registry().snapshot()
+    r = decompose(g, algorithm, "batch", block_edges=64, backend="shard")
+    d = get_registry().delta(before)
+    _same_run(r, ref)
+    per_pass = _probes(g.degrees()) + (2 if algorithm == "semicore*" else 0)
+    assert d[SEGPTR] == r.iterations * per_pass
+    assert d.get(GATHER, 0.0) == 0.0
+
+
+def test_shard_serial_warm_settle_exact():
+    """The on-mesh warm settle (the sharded ``counts_all`` prologue, then
+    ``chunk_semicore_star_shard``) against numpy's."""
+    g = _ends_isolated()
+    core0 = decompose(g, "semicore*", "batch", backend="numpy").core
+    e = g.edge_list()
+
+    def perturbed():
+        bg = BufferedGraph(g)
+        for i in range(40):
+            assert bg.delete_edge(*map(int, e[i * 37]))
+        ins = [(1, 700), (2, 701), (3, 1200), (1499, 5)]
+        return bg, sum(bg.insert_edge(u, v) for u, v in ins)
+
+    bg_np, ni = perturbed()
+    r_np = warm_settle(HostEngine(bg_np, block_edges=64), core0, ni, "numpy")
+    bg_s, _ = perturbed()
+    eng = HostEngine(bg_s, block_edges=64)
+    before = get_registry().snapshot()
+    r_s = warm_settle(eng, core0, ni, "shard")
+    d = get_registry().delta(before)
+    _same_run(r_s, r_np)
+    np.testing.assert_array_equal(r_s.core, imcore_bz(bg_s.materialize()))
+    warm = np.minimum(core0 + ni, eng.degrees())
+    assert d[SEGPTR] == r_s.iterations * (_probes(warm) + 2) + 1
+    assert d.get(GATHER, 0.0) == 0.0
+
+
+def test_shard_masked_settle_exact(monkeypatch):
+    """A delete batch on a shard maintainer settles through the masked
+    sharded chunk program and lands on the numpy maintainer's core and
+    cnt."""
+    g = _ends_isolated()
+    built = []
+    real = resident._shard_chunk_fn
+
+    def spy(*args):
+        built.append(args)
+        return real(*args)
+
+    monkeypatch.setattr(resident, "_shard_chunk_fn", spy)
+    m_s = CoreMaintainer(g, block_edges=64, backend="shard")
+    m_np = CoreMaintainer(g, block_edges=64, backend="numpy")
+    e = g.edge_list()
+    batch = UpdateBatch(tuple(Delete(*map(int, e[i * 29])) for i in range(64)))
+    before = get_registry().snapshot()
+    s = m_s.apply(batch)
+    d = get_registry().delta(before)
+    m_np.apply(batch)
+    assert s.iterations > 0
+    assert any(args[1] == "semicore*" and args[-1] for args in built)
+    np.testing.assert_array_equal(m_s.core, m_np.core)
+    np.testing.assert_array_equal(m_s.cnt, m_np.cnt)
+    np.testing.assert_array_equal(m_s.core, imcore_bz(m_s.bg.materialize()))
+    assert d[SEGPTR] > 0 and d.get(GATHER, 0.0) == 0.0

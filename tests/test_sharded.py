@@ -343,6 +343,7 @@ def walk(x):
 print(json.dumps({
     "n": g.n, "E": int(g.num_directed), "S": int(sg.owned_ids.shape[0]),
     "V": int(sg.owned_ids.shape[1]), "pad": int(sg.pad_edges),
+    "Emax": int(sg.dst.shape[1]), "dmax": int(g.degrees().max()),
     "num_shards": r.num_shards, "shard_pad_edges": r.shard_pad_edges,
     "core_matches_numpy": bool(np.array_equal(r.core, ref.core)),
     "core_matches_peel": bool(np.array_equal(r.core, imcore_bz(g))),
@@ -405,6 +406,26 @@ def test_four_shard_state_h2d_bytes_are_the_arrays_nbytes(four_devices):
     # core (n int32), cnt (S, V) int32, the frontier (S, V) bool, nact
     assert run["delta"]['repro_resident_h2d_bytes_total{what="state"}'] == \
         run["n"] * 4 + S * V * 4 + S * V + 4
+
+
+def test_four_shard_row_bcasts_are_segptr_probes_plus_two(four_devices):
+    """Every pass spreads owned values to edge slots from ``lseg``: its
+    probes, the cnt refresh and the push rule; no gather by ``rows``."""
+    run = four_devices
+    d = run["delta"]
+    probes = max(1, int(np.ceil(np.log2(run["dmax"] + 2))))
+    assert d['repro_resident_row_bcast_total{how="segptr"}'] == \
+        run["walk"][0] * (probes + 2)
+    assert d.get('repro_resident_row_bcast_total{how="gather"}', 0.0) == 0.0
+
+
+def test_four_shard_edge_table_h2d_bytes_hold_no_rows(four_devices):
+    run = four_devices
+    S, V, Emax = run["S"], run["V"], run["Emax"]
+    assert Emax * S == run["E"] + run["pad"]
+    # dst (int32), emask (bool), lseg (int32), owned ids (int32), owned mask
+    assert run["delta"]['repro_resident_h2d_bytes_total{what="edge_table"}'] \
+        == S * Emax * 4 + S * Emax + S * (V + 1) * 4 + S * V * 4 + S * V
 
 
 def test_four_shard_globalize_spans_nest_in_chunk_spans(four_devices):

@@ -166,8 +166,8 @@ def test_shard_chunk_compiles_on_v5e_2x2(topo):
         _vec(LJ_N, jnp.int32, repl), sharded((S, V), jnp.int32),
         sharded((S, V), jnp.bool_), jax.ShapeDtypeStruct((), jnp.int32,
                                                          sharding=repl),
-        sharded((S, Emax), jnp.int32), sharded((S, Emax), jnp.int32),
-        sharded((S, Emax), jnp.bool_), sharded((S, V + 1), jnp.int32),
+        sharded((S, Emax), jnp.int32), sharded((S, Emax), jnp.bool_),
+        sharded((S, V + 1), jnp.int32),
         sharded((S, V), jnp.int32), sharded((S, V), jnp.bool_)).compile()
     # the per-superstep all_gather of core (the compiler may lower it as
     # an all-reduce)
